@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-
 
 def _flatten_pad(x, n):
     flat = x.reshape(-1)
@@ -41,7 +39,7 @@ def hierarchical_psum_local(x, *, in_axis: str = "data", cross_axis: str = "pod"
     Equivalent to psum over (in_axis, cross_axis) but with the rail-optimized
     schedule: cross-axis hop moves only 1/|in_axis| of the bytes.
     """
-    n = axis_size(in_axis)
+    n = jax.lax.axis_size(in_axis)
     flat, pad = _flatten_pad(x, n)
     shard = flat.reshape(n, -1)
     # Phase 1: reduce-scatter in-pod.
@@ -74,7 +72,7 @@ def compressed_cross_pod_psum_local(x, error_shard, *, in_axis: str = "data",
     convergent).  Returns (result, new_error_shard).  The thin cross-pod
     link carries int8 payloads + one fp32 scale per pod: 4× fewer bytes.
     """
-    n = axis_size(in_axis)
+    n = jax.lax.axis_size(in_axis)
     flat, pad = _flatten_pad(x, n)
     shard = flat.reshape(n, -1)
     mine = jax.lax.psum_scatter(shard, in_axis, scatter_dimension=0,
@@ -100,12 +98,12 @@ def hierarchical_psum(x, mesh: Mesh, *, in_axis: str = "data",
     gradient tree leaf laid out with batch sharding on (cross, in)."""
     if cross_axis not in mesh.axis_names:
         # single-pod mesh: plain psum over the in-pod axis
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda v: jax.lax.psum(v, in_axis), mesh=mesh,
             in_specs=P(*(None,) * x.ndim), out_specs=P(*(None,) * x.ndim),
             check_vma=False)
         return fn(x)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(hierarchical_psum_local, in_axis=in_axis, cross_axis=cross_axis),
         mesh=mesh, in_specs=P(*(None,) * x.ndim),
         out_specs=P(*(None,) * x.ndim), check_vma=False)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -98,7 +97,7 @@ def decode_attention_pallas(q, k, v, lengths, *, bk: int = 256,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k, v)
@@ -114,6 +113,12 @@ def decode_attention_pallas(q, k, v, lengths, *, bk: int = 256,
 # index_map reads ``page_table[b, i]`` so the DMA for grid step (b, i) pulls
 # exactly that physical page HBM->VMEM — no contiguous copy of the request's
 # KV is ever materialized.
+#
+# Mosaic takes a block only when its last two dims are multiples of
+# (8, 128) or equal the array's.  A one-row block of a (BH, d) query would
+# be neither, so the query, the output and the per-slot scales travel
+# with a singleton middle axis — (BH, 1, d) and (rows, 1, page) — and
+# their blocks are (1, 1, d) and (1, 1, page).
 
 
 def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
@@ -133,7 +138,7 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
     # point at the scratch page) — skip the whole tile
     @pl.when(i * page < length)
     def _compute():
-        q = q_ref[...].astype(jnp.float32)                  # (1, d)
+        q = q_ref[0].astype(jnp.float32)                    # (1, d)
         k = k_ref[0].astype(jnp.float32)                    # (page, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -151,8 +156,8 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(i == n_pages - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                      ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -174,27 +179,28 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, lengths, *,
         num_scalar_prefetch=2,                   # lengths, page_table
         grid=(bh, n_pages),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, i, lens, pt: (b, 0)),
+            pl.BlockSpec((1, 1, d), lambda b, i, lens, pt: (b, 0, 0)),
             pl.BlockSpec((1, page, d), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
             pl.BlockSpec((1, page, d), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda b, i, lens, pt: (b, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda b, i, lens, pt: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, page=page,
                           n_pages=n_pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, d), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q,
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q[:, None],
       k_pages, v_pages)
+    return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -227,9 +233,9 @@ def _quantized_paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref,
 
     @pl.when(i * page < length)
     def _compute():
-        q = q_ref[...].astype(jnp.float32)                  # (1, d)
+        q = q_ref[0].astype(jnp.float32)                    # (1, d)
         # dequantize in VMEM: values (page, d) * per-slot scales (page, 1)
-        k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None]
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = i * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
@@ -239,15 +245,15 @@ def _quantized_paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_old - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
+        v = v_ref[0].astype(jnp.float32) * vs_ref[0, 0][:, None]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(i == n_pages - 1)
     def _done():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                      ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -279,26 +285,27 @@ def quantized_paged_decode_attention_pallas(q, k_pages, v_pages, k_scale,
         num_scalar_prefetch=2,                   # lengths, page_table
         grid=(bh, n_pages),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, i, lens, pt: (b, 0)),
+            pl.BlockSpec((1, 1, d), lambda b, i, lens, pt: (b, 0, 0)),
             pl.BlockSpec((1, page, d), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
             pl.BlockSpec((1, page, d), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
-            pl.BlockSpec((1, page), lambda b, i, lens, pt: (pt[b, i], 0)),
-            pl.BlockSpec((1, page), lambda b, i, lens, pt: (pt[b, i], 0)),
+            pl.BlockSpec((1, 1, page), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
+            pl.BlockSpec((1, 1, page), lambda b, i, lens, pt: (pt[b, i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda b, i, lens, pt: (b, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda b, i, lens, pt: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_quantized_paged_decode_kernel, scale=scale,
                           page=page, n_pages=n_pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, d), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q,
-      k_pages, v_pages, k_scale, v_scale)
+    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q[:, None],
+      k_pages, v_pages, k_scale[:, None], v_scale[:, None])
+    return out[:, 0]

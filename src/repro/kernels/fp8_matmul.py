@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _fp8_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, o_ref, acc_ref, *,
                        k_steps: int):
@@ -78,7 +76,7 @@ def fp8_matmul_pallas(a_q, b_q, a_scale, b_scale, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_q, b_q, a_scale, b_scale)

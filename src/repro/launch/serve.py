@@ -46,9 +46,9 @@ import numpy as np
 from repro.configs import (get_config, make_example_batch, reduced_config,
                            resolve_arch)
 from repro.core.mixed_precision import KV_DTYPES
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
-from repro.parallel.sharding import rules_for_mesh, DEFAULT_RULES
+from repro.parallel.sharding import SINGLE_DEVICE_RULES
 from repro.runtime.router import FleetModel, ModelFleet, parse_models_spec
 from repro.runtime.sampler import Sampler, SamplingParams
 from repro.runtime.serving import PagedServingEngine
@@ -133,8 +133,7 @@ def serve(arch: str, *, batch: int = 4, prompt_len: int = 32, gen: int = 16,
     cfg = get_config(arch)
     if reduced:
         cfg = reduced_config(cfg)
-    mesh = make_host_mesh()
-    rules = rules_for_mesh(mesh, DEFAULT_RULES)
+    rules = SINGLE_DEVICE_RULES        # one device: no mesh is placed
     opts = M.RunOptions(q_chunk=min(prompt_len, 512), mesh=None)
     max_len = prompt_len + gen
 
@@ -616,6 +615,7 @@ def main():
     add_telemetry_args(ap)
     args = ap.parse_args()
     apply_tuning_preset(args.tuning_preset)
+    enable_compile_cache()
     sampling = sampling_from_args(args)
     try:
         class_precision = (parse_class_precision(args.class_precision)

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.configs.registry import input_specs, input_axes
 from repro.models import model as M
@@ -106,8 +104,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
             """Loss+grads, optionally accumulated over k microbatches (scan):
             (or pipelined over the pod axis when opts.pipeline)."""
             if use_pp:
-                fn = pp_loss_fn(cfg, mesh, inner_rules, opts,
-                                opts.pp_microbatches)
+                fn = pp_loss_fn(cfg, mesh, opts, opts.pp_microbatches)
                 return jax.value_and_grad(fn, has_aux=True)(params, batch)
             return _value_and_grad_mb(params, batch, inner_rules)
 
@@ -198,7 +195,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
                 x_emb = jnp.take(params["embed"], batch["tokens"], axis=0)
                 bb = dict(batch, tok_embeds=x_emb)
                 in_batch_specs = {k: P("pod") for k in bb}
-                fn = shard_map(
+                fn = jax.shard_map(
                     body, mesh=mesh, axis_names={"pod"},
                     in_specs=(P(), P(), in_batch_specs),
                     out_specs=(P(), P(), P(), P(), P("pod")),

@@ -26,6 +26,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_cell
 from repro.models import model as M
@@ -108,6 +109,7 @@ def train(arch: str, *, steps: int = 20, batch: int = 8, seq: int = 128,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--steps", type=int, default=20)

@@ -208,11 +208,11 @@ def paged_attention(p, cfg, x, kv_entry, page_table, qpos, n_valid,
         v_pool = v_pool.at[phys, off].set(v_new)
 
     hd = cfg.resolved_head_dim
+    from repro.kernels import ops
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = "pallas" if ops._on_tpu() else "jnp"
     if impl == "pallas" and C == 1 and kind == "attn":
-        from repro.kernels.ops import paged_decode_attention
-        out = paged_decode_attention(
+        out = ops.paged_decode_attention(
             q, k_pool, v_pool, page_table, qpos[:, 0] + 1,
             k_scale=ks_pool if quantized else None,
             v_scale=vs_pool if quantized else None)

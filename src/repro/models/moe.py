@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.models.modules import ParamSpec, swiglu
 from repro.parallel.sharding import spec_for
 
@@ -184,7 +182,7 @@ def moe_block(p, cfg, x, rules=None, mesh=None,
                                 for k2, v2 in pp["shared"].items()}
         return _moe_local(pp, cfg, xx, psum_axis="model", impl=impl)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
                        out_specs=xspec, check_vma=False)
     return fn(p, x)
 

@@ -11,7 +11,9 @@ Schedule: M microbatches, M+stages-1 ticks; every tick each stage applies
 its local layer groups to its current input and ppermutes the result
 forward.  The loss is computed on the last stage (SPMD-uniform: other
 stages compute-and-mask).  Backward is jax.grad through scan+ppermute —
-the reverse pipeline falls out of autodiff.
+the reverse pipeline falls out of autodiff.  The pipelined region is
+manual over every mesh axis, so within it the data and model axes hold
+replicas of each stage's compute.
 
 Restrictions (asserted): decoder-only dense/ssm-free archs (no MoE
 shard_map nesting, no enc-dec), num_layer_groups % stages == 0.
@@ -25,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import SHARD_MAP_PARTIAL_AUTO, shard_map
 from repro.parallel.sharding import SINGLE_DEVICE_RULES
 
 from repro.models import model as M
@@ -41,19 +42,11 @@ def pp_supported(cfg, mesh: Mesh) -> bool:
     return groups % mesh.shape["pod"] == 0
 
 
-def pp_loss_fn(cfg, mesh: Mesh, rules, opts, num_microbatches: int):
+def pp_loss_fn(cfg, mesh: Mesh, opts, num_microbatches: int):
     """Returns loss(params, batch) with the layer stack pipelined over
     'pod'.  params['blocks'] must be sharded over 'pod' on the group dim
     (rules override 'layers' -> 'pod' — see steps.build_cell)."""
     stages = mesh.shape["pod"]
-    if SHARD_MAP_PARTIAL_AUTO:
-        inner_rules = rules.with_overrides(
-            batch=tuple(a for a in ("data",) if a in mesh.axis_names),
-            layers=None)
-    else:
-        # fully-manual region (0.4.x fallback): no GSPMD inside, so any
-        # constraint naming a mesh axis is illegal — drop them all
-        inner_rules = SINGLE_DEVICE_RULES
 
     def loss(params, batch):
         tokens, labels = batch["tokens"], batch["labels"]
@@ -84,7 +77,7 @@ def pp_loss_fn(cfg, mesh: Mesh, rules, opts, num_microbatches: int):
 
             def stage_fn(x):
                 x, _, _ = M.backbone(blocks_local, cfg, x, positions,
-                                     inner_rules, opts, train=True)
+                                     SINGLE_DEVICE_RULES, opts, train=True)
                 return x
 
             def tick(carry, inp):
@@ -103,9 +96,6 @@ def pp_loss_fn(cfg, mesh: Mesh, rules, opts, num_microbatches: int):
                 return (h_next, acc_loss + valid * total,
                         acc_cnt + valid * count), None
 
-            # init must be TRACED zeros (derived from an input), not eager
-            # jnp.zeros: closed-over array constants get wrong sharding
-            # names in jax 0.4.x's shard_map transpose (_SpecError).
             h0 = xs_pad[0].astype(dt) * 0
             z0 = h0.reshape(-1)[0].astype(jnp.float32)
             init = (h0, z0, z0)
@@ -116,8 +106,12 @@ def pp_loss_fn(cfg, mesh: Mesh, rules, opts, num_microbatches: int):
             cnt = jax.lax.psum(cnt, "pod")
             return tot / jnp.maximum(cnt, 1.0)
 
-        fn = shard_map(
-            body, mesh=mesh, axis_names={"pod"},
+        # manual over EVERY mesh axis (XLA's SPMD partitioner aborts on a
+        # pod-only partial-manual region around this scan): data/model
+        # replicate the stage compute, so the stages run on single-device
+        # rules that name no mesh axis
+        fn = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P("pod"), P(), P(), P()),
             out_specs=P(), check_vma=False)
         out = fn(params["blocks"], non_block, xs_pad, ys_pad)

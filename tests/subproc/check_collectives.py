@@ -13,8 +13,6 @@ import numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.launch.mesh import _make_mesh
 from repro.core.collectives import (hierarchical_psum_local,
                                     compressed_cross_pod_psum_local,
@@ -25,10 +23,10 @@ mesh = _make_mesh((2, 2, 2), ("pod", "data", "model"))
 x = jnp.arange(24.0).reshape(2, 12) / 7.0
 
 # 1. hierarchical == flat psum over (data, pod)
-flat = shard_map(lambda v: jax.lax.psum(v, ("data", "pod")), mesh=mesh,
+flat = jax.shard_map(lambda v: jax.lax.psum(v, ("data", "pod")), mesh=mesh,
                      in_specs=P(None, None), out_specs=P(None, None),
                      check_vma=False)(x)
-hier = shard_map(partial(hierarchical_psum_local, in_axis="data",
+hier = jax.shard_map(partial(hierarchical_psum_local, in_axis="data",
                              cross_axis="pod"),
                      mesh=mesh, in_specs=P(None, None),
                      out_specs=P(None, None), check_vma=False)(x)
@@ -42,7 +40,7 @@ print("OK wrapper")
 
 # 3. compressed psum ≈ flat psum, error bounded by int8 quantization
 err0 = jnp.zeros((x.size // 2,), jnp.float32)
-comp, new_err = shard_map(
+comp, new_err = jax.shard_map(
     partial(compressed_cross_pod_psum_local, in_axis="data", cross_axis="pod"),
     mesh=mesh, in_specs=(P(None, None), P(None)),
     out_specs=(P(None, None), P(None)), check_vma=False)(x, err0)
@@ -56,7 +54,7 @@ print("OK error-feedback")
 
 # 5. hierarchical psum on single-pod mesh (no 'pod' axis)
 mesh2 = _make_mesh((4, 2), ("data", "model"))
-flat2 = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh2,
+flat2 = jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh2,
                       in_specs=P(None, None), out_specs=P(None, None),
                       check_vma=False)(x)
 hier3 = hierarchical_psum(x, mesh2)

@@ -109,6 +109,28 @@ def test_tuning_preset_env(tmp_path):
     assert build_tuning_env("full", tuned, tcmalloc_path=str(lib)) == {}
 
 
+@pytest.mark.parametrize("env_dir", [None, "/srv/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and no other path is set; without
+    it the cache goes to the fixed .jax_cache/ at the checkout root."""
+    from repro.launch import compile_cache
+    set_paths = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: set_paths.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    path = compile_cache.enable_compile_cache()
+    if env_dir is None:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert set_paths == [("jax_compilation_cache_dir", path)]
+    else:
+        assert path == env_dir
+        assert set_paths == []
+
+
 def test_paper_headline_lowprec_claim():
     """Table 9's structural claim in miniature: the FP8/bf16 LU does the
     same O(n³) factor work at lower precision and IR recovers an answer
