@@ -1,0 +1,2 @@
+"""See ``layers.device_idle_share``; the .chat cells."""
+from layers import device_idle_share as read  # noqa: F401
