@@ -146,18 +146,22 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     P, page, KVH, _ = k_pages.shape
     rep = H // KVH
     n = page_table.shape[1]
-    kf = k_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
-    vf = v_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
-    head_base = (jnp.arange(H, dtype=jnp.int32) // rep) * P          # (H,)
-    pt = (head_base[None, :, None] + page_table[:, None, :]
-          ).reshape(B * H, n)
+    # the kernel's layout: one (page, d) row block per (kv head, page);
+    # named so a profile can tell these copies from the rest
+    with jax.named_scope("kv_relayout"):
+        kf = k_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
+        vf = v_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
+        head_base = (jnp.arange(H, dtype=jnp.int32) // rep) * P      # (H,)
+        pt = (head_base[None, :, None] + page_table[:, None, :]
+              ).reshape(B * H, n)
+        if k_scale is not None:
+            # flatten scales exactly like the pools: (P, page, KVH) ->
+            # (KVH*P, page), so pt indexes values and scales identically
+            ksf = k_scale.transpose(2, 0, 1).reshape(KVH * P, page)
+            vsf = v_scale.transpose(2, 0, 1).reshape(KVH * P, page)
     qf = q[:, 0].reshape(B * H, d)
     lens = jnp.repeat(lengths, H)
     if k_scale is not None:
-        # flatten scales exactly like the pools: (P, page, KVH) ->
-        # (KVH*P, page), so pt indexes values and scales identically
-        ksf = k_scale.transpose(2, 0, 1).reshape(KVH * P, page)
-        vsf = v_scale.transpose(2, 0, 1).reshape(KVH * P, page)
         out = quantized_paged_decode_attention_pallas(
             qf, kf, vf, ksf, vsf, pt.astype(jnp.int32),
             lens.astype(jnp.int32), interpret=interpret)

@@ -517,7 +517,7 @@ def report_telemetry(args, telemetry: Optional[Telemetry],
         snap = telemetry.profiler.snapshot()
         print(f"[{tag}] tick-phase profile over {snap['ticks']} ticks:")
         for phase, ph in snap["phases"].items():
-            print(f"[{tag}]   {phase:<16} {ph['total_s'] * 1e3:8.2f} ms "
+            print(f"[{tag}]   {phase:<20} {ph['total_s'] * 1e3:8.2f} ms "
                   f"total  {ph['share'] * 100:5.1f}%")
 
 

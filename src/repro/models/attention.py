@@ -188,34 +188,37 @@ def paged_attention(p, cfg, x, kv_entry, page_table, qpos, n_valid,
     quantized = "ks" in kv_entry
     P, page = k_pool.shape[0], k_pool.shape[1]
     n = page_table.shape[1]
-    q, k_new, v_new = _project_qkv(p, cfg, x, x, qpos, qpos)
+    with jax.named_scope("attn_proj"):
+        q, k_new, v_new = _project_qkv(p, cfg, x, x, qpos, qpos)
 
     valid_tok = jnp.arange(C, dtype=jnp.int32)[None, :] < n_valid[:, None]
     blk = jnp.clip(qpos // page, 0, n - 1)
     phys = jnp.take_along_axis(page_table, blk, axis=1)          # (A, C)
     phys = jnp.where(valid_tok, phys, 0)                         # -> scratch
     off = jnp.where(valid_tok, qpos % page, 0)
-    if quantized:
-        kv_dtype = "fp8" if k_pool.dtype == jnp.uint8 else "int8"
-        kq, ks = mp.quantize_kv_page(k_new, kv_dtype)
-        vq, vs = mp.quantize_kv_page(v_new, kv_dtype)
-        k_pool = k_pool.at[phys, off].set(kq)
-        v_pool = v_pool.at[phys, off].set(vq)
-        ks_pool = kv_entry["ks"].at[phys, off].set(ks)
-        vs_pool = kv_entry["vs"].at[phys, off].set(vs)
-    else:
-        k_pool = k_pool.at[phys, off].set(k_new)
-        v_pool = v_pool.at[phys, off].set(v_new)
+    with jax.named_scope("kv_write"):
+        if quantized:
+            kv_dtype = "fp8" if k_pool.dtype == jnp.uint8 else "int8"
+            kq, ks = mp.quantize_kv_page(k_new, kv_dtype)
+            vq, vs = mp.quantize_kv_page(v_new, kv_dtype)
+            k_pool = k_pool.at[phys, off].set(kq)
+            v_pool = v_pool.at[phys, off].set(vq)
+            ks_pool = kv_entry["ks"].at[phys, off].set(ks)
+            vs_pool = kv_entry["vs"].at[phys, off].set(vs)
+        else:
+            k_pool = k_pool.at[phys, off].set(k_new)
+            v_pool = v_pool.at[phys, off].set(v_new)
 
     hd = cfg.resolved_head_dim
     from repro.kernels import ops
     if impl == "auto":
         impl = "pallas" if ops._on_tpu() else "jnp"
     if impl == "pallas" and C == 1 and kind == "attn":
-        out = ops.paged_decode_attention(
-            q, k_pool, v_pool, page_table, qpos[:, 0] + 1,
-            k_scale=ks_pool if quantized else None,
-            v_scale=vs_pool if quantized else None)
+        with jax.named_scope("paged_attention"):
+            out = ops.paged_decode_attention(
+                q, k_pool, v_pool, page_table, qpos[:, 0] + 1,
+                k_scale=ks_pool if quantized else None,
+                v_scale=vs_pool if quantized else None)
         out = out.astype(q.dtype)
     else:
         if quantized:
@@ -237,8 +240,9 @@ def paged_attention(p, cfg, x, kv_entry, page_table, qpos, n_valid,
     # bf16 compute) attends at pool precision; the residual stream stays
     # in compute dtype either way
     out = out.astype(x.dtype)
-    proj = jnp.einsum("bshd,hdD->bsD", _head_mask(cfg, out),
-                      p["wo"].astype(x.dtype))
+    with jax.named_scope("attn_proj"):
+        proj = jnp.einsum("bshd,hdD->bsD", _head_mask(cfg, out),
+                          p["wo"].astype(x.dtype))
     new_entry = ({"k": k_pool, "v": v_pool, "ks": ks_pool, "vs": vs_pool}
                  if quantized else {"k": k_pool, "v": v_pool})
     return proj, new_entry

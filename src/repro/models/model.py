@@ -456,24 +456,25 @@ def paged_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
                 p["mixer"], cfg, h, c, page_table, qpos,
                 n_valid, kind=kind, impl=opts.paged_attn_impl)
             x = x + mix
-            if mlpk == "moe":
-                hh = rms_norm(x, p["ln2"], cfg.norm_eps)
-                x = x + moe_mod.moe_block(p["moe"], cfg, hh, rules=rules,
-                                          mesh=opts.mesh,
-                                          xaxes=("batch", None, None),
-                                          impl=opts.moe_impl)
-            elif mlpk == "dense":
-                hh = rms_norm(x, p["ln2"], cfg.norm_eps)
-                x = x + swiglu(hh, p["mlp"]["wg"], p["mlp"]["wu"],
-                               p["mlp"]["wd"], x.dtype)
+            with jax.named_scope("mlp"):
+                if mlpk == "moe":
+                    hh = rms_norm(x, p["ln2"], cfg.norm_eps)
+                    x = x + moe_mod.moe_block(
+                        p["moe"], cfg, hh, rules=rules, mesh=opts.mesh,
+                        xaxes=("batch", None, None), impl=opts.moe_impl)
+                elif mlpk == "dense":
+                    hh = rms_norm(x, p["ln2"], cfg.norm_eps)
+                    x = x + swiglu(hh, p["mlp"]["wg"], p["mlp"]["wu"],
+                                   p["mlp"]["wd"], x.dtype)
             new_cac[f"pos{i}"] = nc
         return x, new_cac
 
     x, new_cache = jax.lax.scan(
         lambda carry, xs: block(carry, xs), x, (params["blocks"], cache))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x,
-                        _output_weight(params, cfg).astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x,
+                            _output_weight(params, cfg).astype(x.dtype))
     return logits.astype(jnp.float32), new_cache
 
 
@@ -508,8 +509,9 @@ def fused_decode_tick(params, cfg: ModelConfig, cache, last_tok, pos,
     logits, new_cache = paged_decode_step(
         params, cfg, cache, last_tok[:, None], pos, page_table, n_valid,
         rules, opts)
-    toks = sampler_mod.sample_tokens(logits[:, 0], temperature, top_k,
-                                     top_p, seed, rid, step)
+    with jax.named_scope("sample"):
+        toks = sampler_mod.sample_tokens(logits[:, 0], temperature, top_k,
+                                         top_p, seed, rid, step)
     active = n_valid > 0
     toks = jnp.where(active, toks, last_tok)
     new_pos = pos + n_valid

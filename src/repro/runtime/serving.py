@@ -97,7 +97,11 @@ and small attrs, exportable as Perfetto span timelines and dumped with
 a full engine-state snapshot when ``run`` stalls.  With the default
 ``telemetry=None`` the hot path pays one attribute load + None check
 per event (benchmark workload 9 gates the on/off throughput ratio).
-See ``docs/observability.md``.
+Each step phase (admission, prefill and its first-token pull, the
+decode tick's grow / sync / dispatch / host / sample, bookkeeping) is
+also a ``serve.<phase>`` span on the profiler's clock, so a
+``jax.profiler`` trace puts every device idle gap beside the host work
+around it.  See ``docs/observability.md``.
 
 See ``docs/serving.md`` for the end-to-end architecture guide (tick
 loop, page lifecycle, prefix-cache CoW, lazy growth, preemption replay,
@@ -106,7 +110,6 @@ measure this stack.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import time
@@ -141,10 +144,27 @@ PRIORITIES: Dict[str, int] = {"premium": 0, "standard": 1, "batch": 2}
 
 DEFAULT_PRIORITY = "standard"
 
-#: Reusable no-op context manager: the fused decode tick wraps its
-#: dispatch in ``jax.profiler.TraceAnnotation`` only while the tick
-#: profiler is live, and this stand-in keeps the unprofiled path free.
-_NULL_CTX = contextlib.nullcontext()
+
+class _TimedPhase:
+    """A step phase's span with its wall time added to a
+    :class:`~repro.runtime.telemetry.TickProfiler`: wall time,
+    deliberately not the injected clock, which is virtual under the load
+    harness and would time every phase as 0."""
+
+    __slots__ = ("_name", "_prof", "_span", "_t0")
+
+    def __init__(self, prof, name: str):
+        self._name = name
+        self._prof = prof
+        self._span = jax.profiler.TraceAnnotation("serve." + name)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._prof.add(self._name, time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
 
 
 def priority_level(req: "Request") -> int:
@@ -551,29 +571,31 @@ class Scheduler:
         row the last prompt position's ``(V,)`` logits."""
         if not ready:
             return
-        if all(isinstance(row, np.ndarray) for _, row in ready):
-            # oracle-policy path: rows never left the host, so a jnp
-            # round-trip would only add dispatch latency at harness
-            # scale — same argmax/Sampler algebra on numpy arrays
-            host = np.stack([row for _, row in ready])
-            if all(req.sampling.greedy for req, _ in ready):
-                toks = np.argmax(host, axis=-1).astype(np.int32)
+        with self._phase("prefill/first_token"):
+            if all(isinstance(row, np.ndarray) for _, row in ready):
+                # oracle-policy path: rows never left the host, so a jnp
+                # round-trip would only add dispatch latency at harness
+                # scale — same argmax/Sampler algebra on numpy arrays
+                host = np.stack([row for _, row in ready])
+                if all(req.sampling.greedy for req, _ in ready):
+                    toks = np.argmax(host, axis=-1).astype(np.int32)
+                else:
+                    toks = [self.sampler.sample(host[i], req.sampling,
+                                                rid=req.rid, step=0)
+                            for i, (req, _) in enumerate(ready)]
             else:
-                toks = [self.sampler.sample(host[i], req.sampling,
-                                            rid=req.rid, step=0)
-                        for i, (req, _) in enumerate(ready)]
-        else:
-            rows = jnp.stack([row for _, row in ready])
-            if all(req.sampling.greedy for req, _ in ready):
-                # one on-device argmax over the burst; K ints cross to host
-                toks = np.asarray(  # repro-lint: disable=RL001
-                    jnp.argmax(rows, axis=-1), np.int32)
-            else:
-                # one (K, V) transfer for the whole burst
-                host = np.asarray(rows)  # repro-lint: disable=RL001
-                toks = [self.sampler.sample(host[i], req.sampling,
-                                            rid=req.rid, step=0)
-                        for i, (req, _) in enumerate(ready)]
+                rows = jnp.stack([row for _, row in ready])
+                if all(req.sampling.greedy for req, _ in ready):
+                    # one on-device argmax over the burst; K ints cross
+                    # to host
+                    toks = np.asarray(  # repro-lint: disable=RL001
+                        jnp.argmax(rows, axis=-1), np.int32)
+                else:
+                    # one (K, V) transfer for the whole burst
+                    host = np.asarray(rows)  # repro-lint: disable=RL001
+                    toks = [self.sampler.sample(host[i], req.sampling,
+                                                rid=req.rid, step=0)
+                            for i, (req, _) in enumerate(ready)]
         now = self.clock()
         for (req, _), tok in zip(ready, toks):
             tok = int(tok)
@@ -725,46 +747,35 @@ class Scheduler:
     def step(self):
         """One engine tick: admission, one prefill round, one decode
         round, then a metrics sample (queue depth, active seats, page
-        occupancy overall and per priority class).  When the attached
-        telemetry carries a live tick profiler the phases run through
-        :meth:`_step_profiled` instead (identical order and effects,
-        plus wall-time attribution)."""
+        occupancy overall and per priority class).  Each phase is a
+        ``serve.<phase>`` span on the profiler's clock, and is timed
+        into the telemetry's tick profiler when one is attached."""
+        self.metrics.begin(self.clock())
+        self._tick += 1
+        with self._phase("admission"):
+            self._admit_from_queue()
+        with self._phase("prefill"):
+            self.policy.prefill_tick()
+        with self._phase("decode"):
+            self.policy.decode_tick()
+        with self._phase("bookkeeping"):
+            self._tick_bookkeeping()
         tel = self.telemetry
         if tel is not None and tel.profiler is not None:
-            return self._step_profiled(tel.profiler)
-        self.metrics.begin(self.clock())
-        self._tick += 1
-        self._admit_from_queue()
-        self.policy.prefill_tick()
-        self.policy.decode_tick()
-        self._tick_bookkeeping()
+            tel.profiler.note_tick()
 
-    def _step_profiled(self, prof):
-        """The tick with per-phase wall-time attribution
-        (``admission`` / ``prefill`` / ``decode`` / ``bookkeeping``;
-        the fused paged decode refines its share into ``decode/*``
-        sub-phases).  Measured with ``time.perf_counter`` — profiling
-        is a wall-time tool, deliberately not the injected clock, which
-        is virtual under the harness and would time every phase as 0."""
-        self.metrics.begin(self.clock())
-        self._tick += 1
-        t0 = time.perf_counter()
-        self._admit_from_queue()
-        t1 = time.perf_counter()
-        prof.add("admission", t1 - t0)
-        self.policy.prefill_tick()
-        t2 = time.perf_counter()
-        prof.add("prefill", t2 - t1)
-        self.policy.decode_tick()
-        t3 = time.perf_counter()
-        prof.add("decode", t3 - t2)
-        self._tick_bookkeeping()
-        prof.add("bookkeeping", time.perf_counter() - t3)
-        prof.note_tick()
+    def _phase(self, name: str):
+        """Step phase ``name`` as a span ``serve.<name>`` on the
+        profiler's clock (``jax.profiler.TraceAnnotation``, always on:
+        with no trace active it costs well under a microsecond), timed
+        into the telemetry's tick profiler when one is attached."""
+        tel = self.telemetry
+        if tel is None or tel.profiler is None:
+            return jax.profiler.TraceAnnotation("serve." + name)
+        return _TimedPhase(tel.profiler, name)
 
     def _tick_bookkeeping(self):
-        """The tick's closing metrics sample (shared by the plain and
-        profiled step paths)."""
+        """The tick's closing metrics sample."""
         cached, evictions = self.policy.cache_stats()
         pages_by_class: Dict[str, int] = {}
         for r in self.seats.values():
@@ -1399,71 +1410,52 @@ class PagedPolicy:
         to."""
         sched = self.sched
         if self.lazy:
-            self._grow_tick()
+            with sched._phase("decode/grow"):
+                self._grow_tick()
         decoding = self._decoding_seats()
         if not decoding:
             return
+        if self._dirty:
+            # table/nv re-upload only on churn (admit completion,
+            # finish, preempt, growth all set self._dirty); between
+            # churn events the device mirrors are reused
+            with sched._phase("decode/sync"):
+                self._sync_device()
+        d = self._dev
         if not self.fused:
             tok = np.zeros((self.max_seats, 1), np.int32)
             for s in decoding:
                 tok[s, 0] = sched.seats[s].generated[-1]
-            if self._dirty:
-                self._sync_device()  # table/nv re-upload only on churn
-            d = self._dev
             # per-tick payload: the token batch and the advancing
-            # positions; the page table and valid mask ride the
-            # churn-gated device mirrors (every event that changes them
-            # — admit completion, finish, preempt, growth — sets
-            # self._dirty, so between churn events they are reused)
-            logits, self.cache = self._step_fn(
-                self.params, self.cache,
-                jnp.asarray(tok),       # repro-lint: disable=RL001
-                jnp.asarray(self.pos),  # repro-lint: disable=RL001
-                d["table"], d["nv"])
-            toks = sched._sample_decode_batch(logits[:, 0], decoding)
+            # positions
+            with sched._phase("decode/dispatch"):
+                logits, self.cache = self._step_fn(
+                    self.params, self.cache,
+                    jnp.asarray(tok),       # repro-lint: disable=RL001
+                    jnp.asarray(self.pos),  # repro-lint: disable=RL001
+                    d["table"], d["nv"])
+            with sched._phase("decode/host"):
+                toks = sched._sample_decode_batch(logits[:, 0], decoding)
+        else:
+            # ``fused_decode_tick`` is the dispatch span's older name,
+            # which bench/tracereduce.py still keeps and attributes
+            # idle gaps to
+            with sched._phase("decode/dispatch"), \
+                    jax.profiler.TraceAnnotation("fused_decode_tick"):
+                toks_dev, self.cache, d["pos"], d["step"], d["table"] = \
+                    self._fused_fn(self.params, self.cache, d["last"],
+                                   d["pos"], d["table"], d["nv"],
+                                   d["temp"], d["top_k"], d["top_p"],
+                                   d["seed"], d["rid"], d["step"])
+            d["last"] = toks_dev         # this tick's token = next input
+            with sched._phase("decode/host"):
+                # the tick's ONE device->host sync
+                toks = np.asarray(toks_dev)  # repro-lint: disable=RL001
+        with sched._phase("decode/sample"):
             for s in decoding:
                 req = sched.seats[s]
                 self.pos[s] += 1
-                sched._emit_decode_token(req, toks[s])
-            return
-        # tick profiler: refine the step-level "decode" phase into
-        # sync (device-mirror rebuild) / dispatch (fused-call enqueue)
-        # / host (the ONE blocking token pull) / sample (host
-        # acceptance); perf_counter reads happen only while profiling
-        tel = sched.telemetry
-        prof = None if tel is None else tel.profiler
-        t0 = time.perf_counter() if prof is not None else 0.0
-        if self._dirty:
-            self._sync_device()
-            if prof is not None:
-                now = time.perf_counter()
-                prof.add("decode/sync", now - t0)
-                t0 = now
-        d = self._dev
-        with (jax.profiler.TraceAnnotation("fused_decode_tick")
-              if prof is not None else _NULL_CTX):
-            toks_dev, self.cache, d["pos"], d["step"], d["table"] = \
-                self._fused_fn(self.params, self.cache, d["last"],
-                               d["pos"], d["table"], d["nv"], d["temp"],
-                               d["top_k"], d["top_p"], d["seed"],
-                               d["rid"], d["step"])
-        d["last"] = toks_dev             # this tick's token = next input
-        if prof is not None:
-            now = time.perf_counter()
-            prof.add("decode/dispatch", now - t0)
-            t0 = now
-        # the tick's ONE device->host sync
-        toks = np.asarray(toks_dev)  # repro-lint: disable=RL001
-        if prof is not None:
-            now = time.perf_counter()
-            prof.add("decode/host", now - t0)
-            t0 = now
-        for s in decoding:
-            req = sched.seats[s]
-            self.pos[s] += 1
-            sched._emit_decode_token(req, int(toks[s]))
-        if prof is not None:
-            prof.add("decode/sample", time.perf_counter() - t0)
+                sched._emit_decode_token(req, int(toks[s]))
 
 
 # ---------------------------------------------------------------------------

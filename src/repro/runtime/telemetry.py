@@ -22,9 +22,11 @@ without ever touching a device array:
   *mergeable* :class:`Histogram`\\ s with Prometheus text exposition
   (served by :class:`MetricsServer` behind ``launch/serve.py
   --metrics-port``).
-- :class:`TickProfiler` — wall-time breakdown of the tick phases
-  (admission / prefill / decode, and the fused tick's sync / dispatch /
-  host-crossing / sample sub-phases).
+- :class:`TickProfiler` — wall-time totals of the tick phases
+  (admission / prefill / decode / bookkeeping, the first-token pull,
+  and the paged decode's grow / sync / dispatch / host-crossing /
+  sample sub-phases); the same phases are ``serve.*`` spans on the
+  profiler's clock (``runtime/serving.py``).
 - :class:`BurnRateMonitor` — sliding-window TTFT/TBT miss rates per SLO
   class, emitting edge-triggered ``slo_burn`` warning events.
 
@@ -458,11 +460,14 @@ class TickProfiler:
     """Wall-time breakdown of the engine tick phases.
 
     Phases at the step level: ``admission``, ``prefill``, ``decode``,
-    ``bookkeeping``; the fused decode tick refines its share into
-    ``sync`` (device-mirror rebuild), ``dispatch`` (jitted-call
-    enqueue), ``host`` (the blocking device→host token pull) and
-    ``sample`` (host-side token acceptance).  Measured with
-    ``time.perf_counter`` by the instrumented code — profiling is a
+    ``bookkeeping``.  Sub-phases re-slice their parent's share:
+    ``prefill/first_token`` (the batched first-token pull), and the
+    paged decode's ``decode/grow`` (lazy page growth), ``decode/sync``
+    (device-mirror rebuild), ``decode/dispatch`` (jitted-call
+    enqueue), ``decode/host`` (the blocking device→host token pull) and
+    ``decode/sample`` (host-side token acceptance).  The serving step
+    opens each phase as a span ``serve.<phase>`` on the profiler's clock
+    and adds its ``time.perf_counter`` wall time here — profiling is a
     wall-time tool and stays off under the virtual clock, where every
     phase would read as zero."""
 

@@ -1,7 +1,11 @@
 """One-dispatch decode tick: the fused device-resident path must be
 token-identical to the pre-fusion per-tick engine (``fused=False``), the
 device sampler must match the numpy oracle draw-for-draw, and churn
-(admission / finish / preemption) must never retrace the fused jit."""
+(admission / finish / preemption) must never retrace the fused jit; the
+tick's device work carries its named scopes, and the step's phases reach
+the tick profiler."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +16,16 @@ from repro.models import model as M
 from repro.runtime import sampler as sampler_mod
 from repro.runtime.sampler import Sampler, SamplingParams
 from repro.runtime.serving import PagedServingEngine, ServingEngine
+from repro.runtime.telemetry import Telemetry
+
+#: the named scopes of the fused tick's device work, which a profile
+#: reads from the HLO ``op_name`` metadata
+TICK_SCOPES = ("kv_write", "kv_relayout", "paged_attention", "attn_proj",
+               "mlp", "lm_head", "sample")
+#: the step phases, each a ``serve.<phase>`` span and a profiler entry
+STEP_PHASES = ("admission", "prefill", "prefill/first_token", "decode",
+               "decode/grow", "decode/sync", "decode/dispatch",
+               "decode/host", "decode/sample", "bookkeeping")
 
 
 @pytest.fixture(scope="module")
@@ -160,3 +174,54 @@ def test_first_tokens_batched_share_timestamp(setup):
     done = eng.run()
     stamps = {r.t_first_token for r in done}
     assert len(stamps) == 1
+
+
+# -- names a profile reads ----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tick_hlo(setup):
+    """The fused tick lowered through the Pallas paged kernel (interpret
+    mode off the chip), with its debug locations."""
+    cfg, params = setup
+    eng = PagedServingEngine(cfg, params, page_size=8, num_pages=16,
+                             max_seats=2, max_seq_len=64, prefill_chunk=8,
+                             opts=M.RunOptions(paged_attn_impl="pallas"))
+    pol = eng.policy
+    pol._sync_device()
+    d = pol._dev
+    return pol._fused_fn.lower(
+        pol.params, pol.cache, d["last"], d["pos"], d["table"], d["nv"],
+        d["temp"], d["top_k"], d["top_p"], d["seed"], d["rid"],
+        d["step"]).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", TICK_SCOPES)
+def test_fused_tick_carries_named_scope(tick_hlo, scope):
+    """Each named scope reaches the op names of the lowered tick, as a
+    whole component of the name stack."""
+    assert re.search(rf'["/]{scope}/', tick_hlo), scope
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_tick_profiler_reports_every_phase(setup, fused):
+    """With a tick profiler attached the step reports each phase (the
+    paged decode's sub-phases included) once a tick at most, and serves
+    the tokens it serves without telemetry."""
+    cfg, params = setup
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32)
+               for n in (6, 13, 9)]
+    kw = dict(page_size=4, num_pages=32, max_seats=2, max_seq_len=40,
+              prefill_chunk=8)
+    sps = _mixed_sampling(3)
+    _, want = _run(cfg, params, fused, prompts, [7, 5, 9], sps, **kw)
+    tel = Telemetry(profile=True)
+    eng, got = _run(cfg, params, fused, prompts, [7, 5, 9], sps,
+                    telemetry=tel, **kw)
+    assert got == want
+    snap = tel.profiler.snapshot()
+    assert snap["ticks"] == eng._tick
+    assert set(snap["phases"]) == set(STEP_PHASES)
+    for name, ph in snap["phases"].items():
+        assert 0 < ph["calls"] <= snap["ticks"], name
+        assert ph["total_s"] >= 0
