@@ -129,16 +129,17 @@ def _close(name, got, want, atol, rtol):
 
 def check_kernels(cfg) -> None:
     """The paged decode kernels against ``kernels/ref.py`` on the chip,
-    at the engine's shapes: B*H query rows over a (KVH*P, page, d) pool,
-    as ``ops.paged_decode_attention`` flattens it."""
+    at the engine's shapes: per seat, the ``rep`` query heads of each KV
+    head over a (KVH, P, page, d) pool, as ``ops.paged_decode_attention``
+    lays them out."""
     H, KVH, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    bh, rows, n = SEATS * H, KVH * PAGES, math.ceil(MAX_SEQ_LEN / PAGE)
+    n = math.ceil(MAX_SEQ_LEN / PAGE)
     kq, kk, kv, kp, kl = jax.random.split(jax.random.PRNGKey(SEED + 1), 5)
-    q = jax.random.normal(kq, (bh, d), jnp.bfloat16)
-    k = jax.random.normal(kk, (rows, PAGE, d), jnp.float32)
-    v = jax.random.normal(kv, (rows, PAGE, d), jnp.float32)
-    pt = jax.random.randint(kp, (bh, n), 0, rows, jnp.int32)
-    lens = jax.random.randint(kl, (bh,), 1, n * PAGE + 1, jnp.int32)
+    q = jax.random.normal(kq, (SEATS, KVH, H // KVH, d), jnp.bfloat16)
+    k = jax.random.normal(kk, (KVH, PAGES, PAGE, d), jnp.float32)
+    v = jax.random.normal(kv, (KVH, PAGES, PAGE, d), jnp.float32)
+    pt = jax.random.randint(kp, (SEATS, n), 0, PAGES, jnp.int32)
+    lens = jax.random.randint(kl, (SEATS,), 1, n * PAGE + 1, jnp.int32)
     kb, vb = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
     _close("bf16", paged_decode_attention_pallas(q, kb, vb, pt, lens),
            paged_decode_attention_ref(q, kb, vb, pt, lens),

@@ -129,12 +129,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
 
     When the pool is quantized (fp8/int8), pass ``k_scale``/``v_scale``
     shaped (P, page, KVH) — one f32 scale per stored d-vector — and the
-    quantized kernel dequantizes the tiles in VMEM (both scales must be
-    given together).
+    quantized kernel applies them in VMEM (both scales must be given
+    together).
 
-    GQA expansion happens on the *page table*, not the pool: head h of
-    request b reads pages ``kvh(h) * P + page_table[b]`` of the pool
-    flattened to (KVH*P, page, d) — the big KV arrays are never repeated.
+    The kernel runs one program per (request, KV head) over all ``rep``
+    query heads of the group, so q is viewed as (B, KVH, rep, d) and the
+    pool as (KVH, P, page, d) — the big KV arrays are never repeated.
     """
     from repro.kernels.decode_attention import (
         paged_decode_attention_pallas, quantized_paged_decode_attention_pallas)
@@ -143,30 +143,20 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                          "together (quantized pool) or neither")
     interpret = (not _on_tpu()) if interpret is None else interpret
     B, _, H, d = q.shape
-    P, page, KVH, _ = k_pages.shape
-    rep = H // KVH
-    n = page_table.shape[1]
-    # the kernel's layout: one (page, d) row block per (kv head, page);
-    # named so a profile can tell these copies from the rest
+    KVH = k_pages.shape[2]
+    # the kernel's layout: one (page, d) tile per (kv head, page); named
+    # so a profile can tell these copies from the rest
     with jax.named_scope("kv_relayout"):
-        kf = k_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
-        vf = v_pages.transpose(2, 0, 1, 3).reshape(KVH * P, page, d)
-        head_base = (jnp.arange(H, dtype=jnp.int32) // rep) * P      # (H,)
-        pt = (head_base[None, :, None] + page_table[:, None, :]
-              ).reshape(B * H, n)
+        kf = k_pages.transpose(2, 0, 1, 3)
+        vf = v_pages.transpose(2, 0, 1, 3)
         if k_scale is not None:
-            # flatten scales exactly like the pools: (P, page, KVH) ->
-            # (KVH*P, page), so pt indexes values and scales identically
-            ksf = k_scale.transpose(2, 0, 1).reshape(KVH * P, page)
-            vsf = v_scale.transpose(2, 0, 1).reshape(KVH * P, page)
-    qf = q[:, 0].reshape(B * H, d)
-    lens = jnp.repeat(lengths, H)
+            ksf = k_scale.transpose(2, 0, 1)
+            vsf = v_scale.transpose(2, 0, 1)
+    qf = q.reshape(B, KVH, H // KVH, d)
     if k_scale is not None:
         out = quantized_paged_decode_attention_pallas(
-            qf, kf, vf, ksf, vsf, pt.astype(jnp.int32),
-            lens.astype(jnp.int32), interpret=interpret)
+            qf, kf, vf, ksf, vsf, page_table, lengths, interpret=interpret)
     else:
-        out = paged_decode_attention_pallas(qf, kf, vf, pt.astype(jnp.int32),
-                                            lens.astype(jnp.int32),
+        out = paged_decode_attention_pallas(qf, kf, vf, page_table, lengths,
                                             interpret=interpret)
     return out.reshape(B, 1, H, d)

@@ -63,24 +63,30 @@ def decode_attention_ref(q, k, v, lengths):
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths):
-    """Oracle for paged decode: gather each row's pages into a contiguous
-    cache, then dense decode.  q: (BH, d); k_pages/v_pages: (P, page, d);
-    page_table: (BH, n) int32; lengths: (BH,)."""
-    bh = q.shape[0]
-    _, page, d = k_pages.shape
-    k = k_pages[page_table].reshape(bh, -1, d)     # (BH, n*page, d)
-    v = v_pages[page_table].reshape(bh, -1, d)
-    return decode_attention_ref(q, k, v, lengths)
+    """Oracle for paged decode: gather each seat's pages into a contiguous
+    cache per KV head, then dense decode for each of its query heads.
+    q: (B, KVH, rep, d); k_pages/v_pages: (KVH, P, page, d); page_table:
+    (B, n) int32; lengths: (B,).  Returns (B, KVH, rep, d)."""
+    B, KVH, rep, d = q.shape
+
+    def gather(pages):          # -> (B*KVH*rep, n*page, d)
+        per_head = pages[:, page_table].reshape(KVH, B, -1, d)
+        return jnp.repeat(per_head.transpose(1, 0, 2, 3), rep,
+                          axis=1).reshape(B * KVH * rep, -1, d)
+
+    out = decode_attention_ref(q.reshape(-1, d), gather(k_pages),
+                               gather(v_pages), jnp.repeat(lengths, KVH * rep))
+    return out.reshape(q.shape)
 
 
 def quantized_paged_decode_attention_ref(q, k_pages, v_pages, k_scale,
                                          v_scale, page_table, lengths):
     """Oracle for paged decode over quantized pools: dequantize every
-    page with its per-(slot, head-row) scales, then run the f32 paged
-    oracle.  k_pages/v_pages: (P, page, d) fp8/int8 — uint8 arrays are
-    fp8 bit patterns (core.mixed_precision.kv_storage_dtype) and are
-    bitcast to e4m3 before the value cast; k_scale/v_scale: (P, page)
-    f32 — one scale per stored d-vector."""
+    page with its per-(slot, head) scales, then run the f32 paged
+    oracle.  k_pages/v_pages: (KVH, P, page, d) fp8/int8 — uint8 arrays
+    are fp8 bit patterns (core.mixed_precision.kv_storage_dtype) and are
+    bitcast to e4m3 before the value cast; k_scale/v_scale: (KVH, P,
+    page) f32 — one scale per stored d-vector."""
     if k_pages.dtype == jnp.uint8:
         k_pages = jax.lax.bitcast_convert_type(k_pages, jnp.float8_e4m3fn)
         v_pages = jax.lax.bitcast_convert_type(v_pages, jnp.float8_e4m3fn)

@@ -6,7 +6,8 @@ topology: Mosaic's block-tiling rules and the chip's HBM limit are
 checked on every run without a chip.  Nothing runs and nothing is
 timed.  Shapes are ``chip_smoke.py``'s engine: qwen3-1.7b at published
 widths (28 layers, 16 query / 8 KV heads, head_dim 128, vocab 151,936),
-f32 weights, 8 seats, 16-token pages and a 1024-page bf16 pool.
+f32 weights, 8 seats, 16-token pages and a 1024-page bf16 pool; the paged
+decode kernel also at the two benchmark cells' shapes.
 """
 import jax
 import jax.numpy as jnp
@@ -50,23 +51,35 @@ def _shapes(one_chip, tree):
         tree)
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
-def test_paged_decode_kernel_compiles_for_v5e(one_chip, kv_dtype):
-    cfg = get_config("qwen3-1.7b")
-    H, KVH, d = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    bh, rows, n = SEATS * H, KVH * PAGES, -(-MAX_SEQ_LEN // PAGE)
+# (seats, pages, table width, query heads, KV heads, head_dim): the smoke
+# engine's shapes (a 65-page table, padded to whole blocks), then the two
+# benchmark cells' (bench/cells, bench/configs)
+_SMOKE = (SEATS, PAGES, -(-MAX_SEQ_LEN // PAGE), 16, 8, 128)
+_CELLS = {"qwen3-1.7b.chat": (32, 3335, 64, 16, 8, 128),
+          "minicpm-2b.longctx_batch": (2, 710, 256, 36, 36, 64)}
+_KERNEL_CASES = [pytest.param(_SMOKE, dt, id=dt)
+                 for dt in ("bf16", "int8", "fp8")]
+_KERNEL_CASES += [pytest.param(shape, dt, id=f"{cell}-{dt}")
+                  for cell, shape in _CELLS.items()
+                  for dt in ("bf16", "int8", "fp8")]
+
+
+@pytest.mark.parametrize("shape,kv_dtype", _KERNEL_CASES)
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, shape, kv_dtype):
+    B, P, n, H, KVH, d = shape
     storage = {"bf16": jnp.bfloat16, "int8": jnp.int8, "fp8": jnp.uint8}
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    q, pool = S((bh, d), jnp.bfloat16), S((rows, PAGE, d), storage[kv_dtype])
-    pt, lens = S((bh, n), jnp.int32), S((bh,), jnp.int32)
+    q = S((B, KVH, H // KVH, d), jnp.bfloat16)
+    pool = S((KVH, P, PAGE, d), storage[kv_dtype])
+    pt, lens = S((B, n), jnp.int32), S((B,), jnp.int32)
     if kv_dtype == "bf16":
         lowered = paged_decode_attention_pallas.lower(
             q, pool, pool, pt, lens, interpret=False)
     else:
-        scale = S((rows, PAGE), jnp.float32)
+        scale = S((KVH, P, PAGE), jnp.float32)
         lowered = quantized_paged_decode_attention_pallas.lower(
             q, pool, pool, scale, scale, pt, lens, interpret=False)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    assert lowered.compile().as_text().count("tpu_custom_call") == 1
 
 
 def test_fused_decode_tick_compiles_and_fits_v5e(one_chip, monkeypatch):

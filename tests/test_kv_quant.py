@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config, reduced_config
 from repro.core import mixed_precision as mp
@@ -72,15 +73,17 @@ def test_kv_token_bytes_and_precision_bits():
 
 # -- quantized kernel vs references -------------------------------------------
 
-def _paged_problem(seed, BH=6, d=32, P=16, page=8, n=4):
+def _paged_problem(seed, B=3, KVH=2, rep=2, d=32, P=16, page=8, n=4):
+    """q (B, KVH, rep, d) and pools (KVH, P, page, d) in the kernel's
+    layout, with each seat's live pages on distinct physical pages."""
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.normal(size=(BH, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, d)), jnp.float32)
-    pt = np.zeros((BH, n), np.int32)
-    lengths = rng.integers(1, n * page, size=(BH,)).astype(np.int32)
+    q = jnp.asarray(rng.normal(size=(B, KVH, rep, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(KVH, P, page, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(KVH, P, page, d)), jnp.float32)
+    pt = np.zeros((B, n), np.int32)
+    lengths = rng.integers(1, n * page, size=(B,)).astype(np.int32)
     avail = list(range(1, P))
-    for b in range(BH):
+    for b in range(B):
         for i in range(-(-int(lengths[b]) // page)):
             pt[b, i] = avail.pop()
     return q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths)
@@ -93,8 +96,8 @@ def test_quantized_kernel_matches_oracle(kv_dtype):
     q, kp, vp, pt, lengths = _paged_problem(0)
     kq, ks = mp.quantize_kv_page(kp, kv_dtype)
     vq, vs = mp.quantize_kv_page(vp, kv_dtype)
-    out = quantized_paged_decode_attention_pallas(q, kq, vq, ks, vs, pt,
-                                                  lengths, interpret=True)
+    out = quantized_paged_decode_attention_pallas(
+        q, kq, vq, ks, vs, pt, lengths, interpret=pltpu.InterpretParams())
     want = quantized_paged_decode_attention_ref(q, kq, vq, ks, vs, pt,
                                                 lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=5e-6)
@@ -108,10 +111,10 @@ def test_quantized_kernel_close_to_full_precision(kv_dtype):
     q, kp, vp, pt, lengths = _paged_problem(1)
     kq, ks = mp.quantize_kv_page(kp, kv_dtype)
     vq, vs = mp.quantize_kv_page(vp, kv_dtype)
-    out = quantized_paged_decode_attention_pallas(q, kq, vq, ks, vs, pt,
-                                                  lengths, interpret=True)
+    out = quantized_paged_decode_attention_pallas(
+        q, kq, vq, ks, vs, pt, lengths, interpret=pltpu.InterpretParams())
     full = paged_decode_attention_pallas(q, kp, vp, pt, lengths,
-                                         interpret=True)
+                                         interpret=pltpu.InterpretParams())
     # outputs are convex combinations of unit-scale v rows: abs error
     # bounded by the per-element quantization error plus softmax shift
     tol = 0.25 if kv_dtype == "fp8" else 0.08
@@ -156,11 +159,13 @@ def test_quantized_ops_wrapper_gqa_expansion():
 
 def test_ops_wrapper_requires_scale_pair():
     q, kp, vp, pt, lengths = _paged_problem(4)
+    B, KVH, rep, d = q.shape
     kq, ks = mp.quantize_kv_page(kp, "fp8")
     with pytest.raises(ValueError, match="together"):
-        ops.paged_decode_attention(q[:, None, :, None].transpose(0, 1, 3, 2),
-                                   kq[:, :, None], vp[:, :, None],
-                                   pt, lengths, k_scale=ks[:, :, None])
+        ops.paged_decode_attention(q.reshape(B, 1, KVH * rep, d),
+                                   kq.transpose(1, 2, 0, 3),
+                                   vp.transpose(1, 2, 0, 3), pt, lengths,
+                                   k_scale=ks.transpose(1, 2, 0))
 
 
 # -- cache layout and byte accounting -----------------------------------------

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config, reduced_config
 from repro.kernels import ops
@@ -55,34 +56,88 @@ def test_pages_needed_rounding():
 
 # -- kernel vs references ----------------------------------------------------
 
-def test_paged_kernel_matches_refs():
-    rng = np.random.default_rng(0)
-    BH, d, P, page, n = 6, 32, 16, 8, 4
-    q = jnp.asarray(rng.normal(size=(BH, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, d)), jnp.float32)
-    pt = np.zeros((BH, n), np.int32)
-    lengths = rng.integers(1, n * page, size=(BH,)).astype(np.int32)
-    avail = list(range(1, P))
-    for b in range(BH):
+def _paged_problem(rng, B, KVH, rep, d, P, page, n, lengths, dtype=jnp.float32,
+                   shared=False):
+    """Random q and pools in the kernel's layout and a page table that maps
+    each seat's live pages to shuffled physical pages (dead entries name
+    the scratch page 0).  ``shared``: seats 0 and 1 map their first
+    logical page to one physical page."""
+    q = jnp.asarray(rng.normal(size=(B, KVH, rep, d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(KVH, P, page, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(KVH, P, page, d)), dtype)
+    pt = np.zeros((B, n), np.int32)
+    avail = list(rng.permutation(np.arange(1, P)))
+    for b in range(B):
         for i in range(-(-int(lengths[b]) // page)):
             pt[b, i] = avail.pop()
-    out = paged_decode_attention_pallas(q, kp, vp, jnp.asarray(pt),
-                                        jnp.asarray(lengths), interpret=True)
-    ref = paged_decode_attention_ref(q, kp, vp, jnp.asarray(pt),
-                                     jnp.asarray(lengths))
+    if shared:
+        pt[1, 0] = pt[0, 0]
+    return q, kp, vp, jnp.asarray(pt), jnp.asarray(lengths, jnp.int32)
+
+
+# The TPU interpreter runs the kernel's DMAs only when they are waited on,
+# and fills fresh scratch with NaN: a missing wait or a stale buffer shows
+TPU_INTERPRET = pltpu.InterpretParams()
+
+# (B, KVH, rep, d, P, page, n, lengths, shared): 256-token blocks, so with
+# 64-token pages a block is 4 pages: an 8-page table holds 2 blocks and a
+# 3-page one is padded to one.  Heads of 64 and 32 are packed 2 and 4
+# tokens to a 128-lane row; heads of 96 are padded to 128 lanes.
+PAGED_CASES = {
+    "mha_d64_len1_page_and_block_edges": (4, 3, 1, 64, 40, 64, 8,
+                                          [1, 64, 256, 257], False),
+    "gqa_d128_full_table": (3, 2, 2, 128, 32, 64, 8, [512, 300, 65], False),
+    "table_not_a_block_multiple": (3, 2, 2, 128, 32, 64, 3,
+                                   [192, 129, 64], False),
+    "shared_physical_page": (3, 2, 2, 64, 24, 64, 4, [100, 200, 17], True),
+    "small_pages_f32": (6, 1, 1, 32, 16, 8, 4, [1, 8, 9, 17, 31, 32], False),
+    "head_padded_to_lanes": (3, 2, 2, 96, 24, 64, 4, [256, 100, 1], False),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_kernel_matches_refs(case):
+    B, KVH, rep, d, P, page, n, lengths, shared = PAGED_CASES[case]
+    rng = np.random.default_rng(0)
+    q, kp, vp, pt, lens = _paged_problem(rng, B, KVH, rep, d, P, page, n,
+                                         lengths, shared=shared)
+    out = paged_decode_attention_pallas(q, kp, vp, pt, lens,
+                                        interpret=TPU_INTERPRET)
+    ref = paged_decode_attention_ref(q, kp, vp, pt, lens)
+    assert out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # the paged ref itself must equal dense decode on the gathered cache
-    k = np.asarray(kp)[pt].reshape(BH, -1, d)
-    v = np.asarray(vp)[pt].reshape(BH, -1, d)
-    dense = decode_attention_ref(q, jnp.asarray(k), jnp.asarray(v),
-                                 jnp.asarray(lengths))
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(dense), atol=1e-6)
+    for h in range(KVH):
+        k = np.asarray(kp)[h][np.asarray(pt)].reshape(B, -1, d)
+        v = np.asarray(vp)[h][np.asarray(pt)].reshape(B, -1, d)
+        for r in range(rep):
+            dense = decode_attention_ref(q[:, h, r], jnp.asarray(k),
+                                         jnp.asarray(v), lens)
+            np.testing.assert_allclose(np.asarray(ref[:, h, r]),
+                                       np.asarray(dense), atol=1e-6)
 
 
-def test_paged_ops_wrapper_gqa_expansion():
+def test_paged_kernel_bf16_pool_within_one_rounding():
+    """bf16 q and pool go to the MXU unconverted: the output differs from
+    the f32 oracle on the same bf16 values by at most the bf16 rounding of
+    the result."""
+    rng = np.random.default_rng(3)
+    q, kp, vp, pt, lens = _paged_problem(rng, 3, 2, 2, 128, 32, 64, 8,
+                                         [512, 257, 1], dtype=jnp.bfloat16)
+    out = paged_decode_attention_pallas(q, kp, vp, pt, lens,
+                                        interpret=TPU_INTERPRET)
+    ref = paged_decode_attention_ref(q.astype(jnp.float32),
+                                     kp.astype(jnp.float32),
+                                     vp.astype(jnp.float32), pt, lens)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=1e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("H,KVH,d", [(4, 2, 16), (3, 3, 64)])
+def test_paged_ops_wrapper_gqa_expansion(H, KVH, d):
     rng = np.random.default_rng(1)
-    B, H, KVH, d, P, page, n = 3, 4, 2, 16, 12, 8, 3
+    B, P, page, n = 3, 12, 8, 3
     q = jnp.asarray(rng.normal(size=(B, 1, H, d)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, page, KVH, d)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(P, page, KVH, d)), jnp.float32)
