@@ -6,10 +6,35 @@ files and entries, and edits nothing that is here.
 
     BENCHMARK.json                      cells and metrics
     bench/configs/<config>.json         model configuration (sizes as run)
-    bench/reference/<reference>.py      its plain reference forward
+    bench/reference/<reference>.py      its weight layout, plain reference
+                                        forward and output head
     bench/traffic/<traffic>.json        traffic-mix parameters
     bench/cells/<workload>.json         engine settings of one cell
     bench/metrics/<metric>.py           reader of one metric: read(run)
+
+A new configuration brings ``configs/<c>.json``, which names the
+program's registry entry (``arch``), the sizes put into it (``model``),
+the serving dtype (``weights_dtype``) and its reference module
+(``reference``); and, unless an existing module fits, that module,
+``reference/<r>.py``, which is the one place that knows the
+configuration's weights and head:
+
+    shapes(model) -> {path: (shape, std)}   every leaf, in the program's
+                                            layout; ``weights.make`` draws it
+    hidden(params, tokens, model, fp8)      final normed hidden states
+                                            (B, T, D), float32; ``fp8`` is
+                                            the control's precision
+    head_stats(params, h, targets)          (best logit, logit of target)
+                                            per row of ``h``
+    head_argmax_fp8(params, h)              the control's choice per row
+    HEAD_BLOCK                              rows a head block takes
+
+It reads what its maths needs from ``model`` and picks its own output
+head from ``params``, and imports nothing of the program.  Its cells
+bring ``traffic/``, ``cells/`` and ``metrics/`` files.  ``work.py``
+counts the work of a dense decoder (every matmul parameter once per
+token); a configuration that is not one (experts, latent attention)
+computes its per-layer metrics' work in its own new metric files.
 """
 from __future__ import annotations
 

@@ -12,7 +12,8 @@ Order of a run:
 
  1. device check (a TPU with as many chips as the cell asks for);
  2. persistent compile cache at ``<checkout>/.jax_cache``;
- 3. weights from the seed, on the device, in one jitted call;
+ 3. weights from the seed, on the device, in one jitted call, laid out
+    by the configuration's reference module;
  4. the cell's engine;
  5. shape warm-up: one request through one prefill chunk and the fused
     tick, so the window compiles nothing;
@@ -469,7 +470,10 @@ def served_gaps(ref, params, model: dict, sample: List[Record],
     """For each served token of ``sample``: how far its logit lies
     below the reference's best at that position.  With ``control``,
     also the same for the token the control (the reference in fp8)
-    puts first.  ``rows`` x ``length`` is the fixed padded batch."""
+    puts first.  ``rows`` x ``length`` is the fixed padded batch.
+    ``ref`` is the configuration's reference module: it takes from
+    ``model`` what its maths needs and picks its own output head from
+    ``params``."""
     import jax.numpy as jnp
     tokens = np.zeros((rows, length), np.int32)
     where_b, where_t, targets = [], [], []
@@ -489,21 +493,18 @@ def served_gaps(ref, params, model: dict, sample: List[Record],
     wb = np.asarray(where_b + [0] * pad)
     wt = np.asarray(where_t + [0] * pad)
     tg = jnp.asarray(np.asarray(targets + [0] * pad, np.int32))
-    eps, theta = float(model["norm_eps"]), float(model["rope_theta"])
-    h = ref.hidden(params, jnp.asarray(tokens), eps=eps, theta=theta,
-                   fp8=False)
+    h = ref.hidden(params, jnp.asarray(tokens), model, fp8=False)
     rows_ref = h[wb, wt]
-    best, got = ref.head_stats(params["embed"], rows_ref, tg)
+    best, got = ref.head_stats(params, rows_ref, tg)
     gaps = np.asarray(best - got)[:n]
     out = {"tokens": n, "max_gap": float(gaps.max()),
            "mean_gap": float(gaps.mean())}
     if control:
         del h
-        hc = ref.hidden(params, jnp.asarray(tokens), eps=eps, theta=theta,
-                        fp8=True)
-        pick = ref.head_argmax_fp8(params["embed"], hc[wb, wt])
+        hc = ref.hidden(params, jnp.asarray(tokens), model, fp8=True)
+        pick = ref.head_argmax_fp8(params, hc[wb, wt])
         del hc
-        best, got = ref.head_stats(params["embed"], rows_ref, pick)
+        best, got = ref.head_stats(params, rows_ref, pick)
         cg = np.asarray(best - got)[:n]
         out["control_max_gap"] = float(cg.max())
         out["control_mean_gap"] = float(cg.mean())
@@ -552,7 +553,9 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
 
     s = cell.settings
     model = cell.config["model"]
-    params = weights_mod.make(model, seed, cell.config["weights_dtype"])
+    ref = cat.reference(cell.config)
+    params = weights_mod.make(ref.shapes(model), seed,
+                              cell.config["weights_dtype"])
     eng = PagedServingEngine(
         program_config(cell.config), params, page_size=s["page_size"],
         num_pages=s["num_pages"], max_seats=s["seats"],
@@ -571,7 +574,6 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     peak = stats.get("peak_bytes_in_use")
 
     # the check, once the window has closed and the engine is freed
-    ref = cat.reference(cell.config)
     chk = s["check"]
     sample = pick_sample(r, int(chk["requests"]), seed)
     eng_refused = sum(1 for x in r.records if x.refused)

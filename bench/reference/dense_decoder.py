@@ -14,25 +14,31 @@ independent of the program (it imports nothing from ``repro``):
     h        = rms(x) * (1 + g_mlp)
     x        = x + (silu(h Wg) * (h Wu)) Wd
     logits   = (rms(x) * (1 + g_final)) E^T        (tied head)
+             = (rms(x) * (1 + g_final)) W_head     (untied: lm_head (D, V))
 
 with rms(x) = x / sqrt(mean(x^2) + eps).  Norm scales are stored as an
-offset from 1, as the benchmark's weights (``bench/weights.py``) lay
-them out.  MiniCPM's scalar multipliers (scale_emb, scale_depth,
-dim_model_base) are left out, as the program leaves them out; the
-configuration file lists them under ``departures``.
+offset from 1, as ``shapes`` lays them out.  MiniCPM's scalar
+multipliers (scale_emb, scale_depth, dim_model_base) are left out, as
+the program leaves them out; the configuration file lists them under
+``departures``.
 
 Every matmul runs at ``Precision.HIGHEST`` (full float32 on a TPU).  The
 layers run one at a time under ``lax.scan`` with each layer's weights
 raised to float32 inside the step, and attention runs in blocks of
 query rows, so the reference fits beside the weights.
 
-``precision="fp8"`` is the control: the same forward with every matmul
-input rounded to float8 e4m3 (scaled per vector along the contraction,
-as an fp8 serving path would), accumulated in float32.
+``fp8=True`` is the control: the same forward with every matmul input
+rounded to float8 e4m3 (scaled per vector along the contraction, as an
+fp8 serving path would), accumulated in float32.
+
+The module also owns the configuration's weight layout (``shapes``)
+and picks its output head from ``params``: the names the harness asks
+of a reference module are listed in ``bench/catalog.py``.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +47,47 @@ HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
 Q_BLOCK = 256
 HEAD_BLOCK = 256
+NORM_STD = 0.1
+
+
+def shapes(model: dict) -> dict:
+    """{path: (shape, std)} for every leaf, in the program's layout
+    (``repro.models.model`` ``param_specs`` for a pure-attention decoder
+    with one layer kind):
+
+        embed (V, D)                    input embedding (and the tied head)
+        lm_head (D, V)                  output head, untied only
+        final_norm (D,)
+        blocks/pos0/ln1, ln2 (L, D)     RMSNorm offsets, applied as (1 + w)
+        blocks/pos0/mixer/wq (L, D, H, hd), wk / wv (L, D, KVH, hd),
+                          wo (L, H, hd, D), q_norm / k_norm (L, hd)
+        blocks/pos0/mlp/wg / wu (L, D, F), wd (L, F, D)
+
+    Scales: projections N(0, 1/fan_in), the embedding and the untied
+    head N(0, 1/D) so the head gives logits of unit spread, norm offsets
+    N(0, 0.1^2)."""
+    L, D = model["num_layers"], model["d_model"]
+    H, KVH = model["num_heads"], model["num_kv_heads"]
+    hd, F, V = model["head_dim"], model["d_ff"], model["vocab_size"]
+    leaves = {
+        "embed": ((V, D), 1.0 / math.sqrt(D)),
+        "final_norm": ((D,), NORM_STD),
+        "blocks/pos0/ln1": ((L, D), NORM_STD),
+        "blocks/pos0/ln2": ((L, D), NORM_STD),
+        "blocks/pos0/mixer/wq": ((L, D, H, hd), 1.0 / math.sqrt(D)),
+        "blocks/pos0/mixer/wk": ((L, D, KVH, hd), 1.0 / math.sqrt(D)),
+        "blocks/pos0/mixer/wv": ((L, D, KVH, hd), 1.0 / math.sqrt(D)),
+        "blocks/pos0/mixer/wo": ((L, H, hd, D), 1.0 / math.sqrt(H * hd)),
+        "blocks/pos0/mlp/wg": ((L, D, F), 1.0 / math.sqrt(D)),
+        "blocks/pos0/mlp/wu": ((L, D, F), 1.0 / math.sqrt(D)),
+        "blocks/pos0/mlp/wd": ((L, F, D), 1.0 / math.sqrt(F)),
+    }
+    if not model.get("tie_embeddings", False):
+        leaves["lm_head"] = ((D, V), 1.0 / math.sqrt(D))
+    if model.get("qk_norm", False):
+        leaves["blocks/pos0/mixer/q_norm"] = ((L, hd), NORM_STD)
+        leaves["blocks/pos0/mixer/k_norm"] = ((L, hd), NORM_STD)
+    return leaves
 
 
 def _q8(x, axis):
@@ -98,10 +145,15 @@ def _attend(q, k, v, fp8):
     return out.swapaxes(0, 1).reshape(B, T, H, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "fp8"))
-def hidden(params, tokens, *, eps: float, theta: float, fp8: bool):
+def hidden(params, tokens, model: dict, fp8: bool):
     """Final normed hidden states (B, T, D) in float32 for ``tokens``
     (B, T), every row a sequence from position 0."""
+    return _hidden(params, tokens, eps=float(model["norm_eps"]),
+                   theta=float(model["rope_theta"]), fp8=fp8)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "theta", "fp8"))
+def _hidden(params, tokens, *, eps: float, theta: float, fp8: bool):
     B, T = tokens.shape
     x = params["embed"][tokens].astype(jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(T), (B, T))
@@ -132,15 +184,30 @@ def hidden(params, tokens, *, eps: float, theta: float, fp8: bool):
     return _rms(x, params["final_norm"].astype(jnp.float32), eps)
 
 
-@jax.jit
-def head_stats(embed, h_ref, targets):
-    """Reference logits of rows ``h_ref`` (R, D) against the tied head:
-    returns (best logit, logit of ``targets``) per row."""
-    E = embed.astype(jnp.float32)
+def _head(params):
+    """The output head as stored, and whether it is laid out (D, V):
+    ``lm_head`` where the configuration unties it, else the embedding
+    (V, D)."""
+    if "lm_head" in params:
+        return params["lm_head"], True
+    return params["embed"], False
+
+
+def head_stats(params, h_ref, targets):
+    """Reference logits of rows ``h_ref`` (R, D) against the output
+    head: returns (best logit, logit of ``targets``) per row."""
+    w, dv = _head(params)
+    return _head_stats(w, h_ref, targets, dv=dv)
+
+
+@functools.partial(jax.jit, static_argnames=("dv",))
+def _head_stats(w, h_ref, targets, *, dv: bool):
+    E = w.astype(jnp.float32)
+    eq = "rd,dv->rv" if dv else "rd,vd->rv"
 
     def block(args):
         h, t = args
-        lg = jnp.einsum("rd,vd->rv", h, E, precision=HIGHEST)
+        lg = jnp.einsum(eq, h, E, precision=HIGHEST)
         return (jnp.max(lg, -1),
                 jnp.take_along_axis(lg, t[:, None], -1)[:, 0])
 
@@ -151,13 +218,19 @@ def head_stats(embed, h_ref, targets):
     return best.reshape(R), tgt.reshape(R)
 
 
-@jax.jit
-def head_argmax_fp8(embed, h_ctrl):
+def head_argmax_fp8(params, h_ctrl):
     """The control's choice per row: argmax of its fp8 head logits."""
-    E = _q8(embed.astype(jnp.float32), -1)
+    w, dv = _head(params)
+    return _head_argmax_fp8(w, h_ctrl, dv=dv)
+
+
+@functools.partial(jax.jit, static_argnames=("dv",))
+def _head_argmax_fp8(w, h_ctrl, *, dv: bool):
+    E = _q8(w.astype(jnp.float32), 0 if dv else -1)
+    eq = "rd,dv->rv" if dv else "rd,vd->rv"
 
     def block(h):
-        return jnp.argmax(jnp.einsum("rd,vd->rv", _q8(h, -1), E,
+        return jnp.argmax(jnp.einsum(eq, _q8(h, -1), E,
                                      precision=HIGHEST), -1).astype(jnp.int32)
 
     R, D = h_ctrl.shape

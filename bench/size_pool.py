@@ -37,7 +37,7 @@ import arrivals  # noqa: E402
 MARGIN = 1.0 * 2 ** 30
 
 
-def analyse(cell, pages: int, one_chip):
+def analyse(cell, ref, pages: int, one_chip):
     import jax
     import jax.numpy as jnp
     import harness
@@ -53,7 +53,7 @@ def analyse(cell, pages: int, one_chip):
     dt = jnp.dtype(cell.config["weights_dtype"])
     params = jax.tree.map(
         lambda spec: jax.ShapeDtypeStruct(spec[0], dt, sharding=one_chip),
-        W._nest(W.shapes(cell.config["model"])),
+        W._nest(ref.shapes(cell.config["model"])),
         is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
     cache = sh(jax.eval_shape(lambda: M.init_paged_cache(
         cfg, pages, s["page_size"])))
@@ -87,22 +87,23 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import catalog
-    import weights as W
     from repro.models import model as M
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    cell = catalog.Catalog(ROOT).cell(args.workload)
+    cat = catalog.Catalog(ROOT)
+    cell = cat.cell(args.workload)
+    ref = cat.reference(cell.config)
     s = cell.settings
     itemsize = jax.numpy.dtype(cell.config["weights_dtype"]).itemsize
     wbytes = sum(math.prod(shape) for shape, _ in
-                 W.shapes(cell.config["model"]).values()) * itemsize
+                 ref.shapes(cell.config["model"]).values()) * itemsize
     import harness
     cfg = harness.program_config(cell.config)
     page_bytes = M.paged_page_bytes(cfg, s["page_size"])
     def temps(p):
-        tick, pre, _ = analyse(cell, p, one)
+        tick, pre, _ = analyse(cell, ref, p, one)
         t = (tick.temp_size_in_bytes,
              pre.temp_size_in_bytes + pre.output_size_in_bytes
              - pre.alias_size_in_bytes)

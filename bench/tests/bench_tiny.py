@@ -1,5 +1,6 @@
 """Tiny cells for the benchmark's CPU tests: the harness, the engine and
-the reference at a few thousand parameters."""
+the reference at a few thousand parameters, with a tied or an untied
+output head."""
 import os
 import sys
 
@@ -10,6 +11,11 @@ for p in (BENCH, os.path.join(ROOT, "src")):
         sys.path.insert(0, p)
 
 import catalog  # noqa: E402
+import weights  # noqa: E402
+
+REF = catalog.load_module(os.path.join(BENCH, "reference",
+                                       "dense_decoder.py"),
+                          "bench_reference_dense_decoder")
 
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
          "hbm_bytes": 16e9}
@@ -25,6 +31,12 @@ def model(qk_norm=True, kv_heads=2, **over):
     return m
 
 
+def params(m, seed, dtype):
+    """The benchmark's weights for model dict ``m``, as a run makes
+    them: the reference's layout drawn from the seed."""
+    return weights.make(REF.shapes(m), seed, dtype)
+
+
 def config(m=None, arch="qwen3-1.7b"):
     return {"name": "tiny", "arch": arch, "model": m or model(),
             "weights_dtype": "bfloat16", "reference": "dense_decoder"}
@@ -34,7 +46,11 @@ def metric(name, e2e):
     return catalog.Metric(name, "x", e2e, None)
 
 
-def chat_cell(limit=0.5):
+def _name(kind, tie):
+    return f"tiny.{kind}" if tie else f"tiny.{kind}.untied"
+
+
+def chat_cell(limit=0.5, tie=True):
     traffic = {"kind": "open_loop", "burstiness": 1.0,
                "prompt": {"dist": "lognormal", "median": 20, "sigma": 0.8,
                           "min": 4, "max": 64},
@@ -47,12 +63,13 @@ def chat_cell(limit=0.5):
                 "check": {"requests": 4, "max_logit_gap": limit}}
     e2e = ["setup_s", "ttft_p90_ms", "tbt_p95_ms"]
     pl = ["queue_wait_p90_ms.chat"]
-    return catalog.Cell("tiny.chat", 1, config(), traffic, settings,
+    return catalog.Cell(_name("chat", tie), 1,
+                        config(model(tie_embeddings=tie)), traffic, settings,
                         [metric(n, True) for n in e2e],
                         [metric(n, False) for n in pl])
 
 
-def batch_cell(limit=0.5):
+def batch_cell(limit=0.5, tie=True):
     traffic = {"kind": "replay",
                "requests": [{"prompt": 40, "output": 60},
                             {"prompt": 90, "output": 30},
@@ -63,6 +80,7 @@ def batch_cell(limit=0.5):
                 "check": {"requests": 3, "max_logit_gap": limit}}
     e2e = ["setup_s", "output_tokens_per_s"]
     pl = ["seats_busy_mean.batch", "kv_pages_used_share.batch"]
-    return catalog.Cell("tiny.batch", 1, config(), traffic, settings,
+    return catalog.Cell(_name("batch", tie), 1,
+                        config(model(tie_embeddings=tie)), traffic, settings,
                         [metric(n, True) for n in e2e],
                         [metric(n, False) for n in pl])

@@ -1,8 +1,10 @@
 """Whole runs of tiny cells on the CPU, the chip check left out: a
 sound run comes out correct, and a run whose timed path alters a token
-where it is produced comes out not correct."""
+where it is produced comes out not correct, with a tied output head and
+with an untied one (``lm_head``, laid out by the reference module)."""
 import bench_tiny  # noqa: F401  (paths)
 
+import functools
 import time
 
 import jax.numpy as jnp
@@ -83,7 +85,10 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(M, "fused_decode_tick", faulty)
 
 
-CELLS = {"chat": bench_tiny.chat_cell, "batch": bench_tiny.batch_cell}
+CELLS = {"chat": bench_tiny.chat_cell, "batch": bench_tiny.batch_cell,
+         "chat.untied": functools.partial(bench_tiny.chat_cell, tie=False),
+         "batch.untied": functools.partial(bench_tiny.batch_cell,
+                                           tie=False)}
 
 
 @pytest.mark.parametrize("kind", sorted(CELLS))
@@ -110,17 +115,23 @@ def test_broken_timed_path_is_not_correct(kind, fault, monkeypatch):
     assert not line["correct"], line["check"]
 
 
-def test_control_fails_the_chat_limit():
-    """The control (the reference in float8) against the chat cell's
-    own limit, at a small size at which program and control read like
-    the chip's full-size runs (about 0.04 and 0.5): eight layers, width
-    256, 8192 tokens."""
+def _control_cell(tie):
+    """The tiny chat cell at the chat cell's own limit, at a small size
+    at which program and control read like the chip's full-size runs
+    (about 0.04 and 0.5): eight layers, width 256, 8192 tokens."""
     limit = catalog.Catalog(bench_tiny.ROOT).cell("qwen3-1.7b.chat") \
         .settings["check"]["max_logit_gap"]
-    cell = bench_tiny.chat_cell(limit)
+    cell = bench_tiny.chat_cell(limit, tie=tie)
     cell.config = bench_tiny.config(bench_tiny.model(
         num_layers=8, d_model=256, vocab_size=8192, num_heads=8,
-        kv_heads=4, head_dim=32, d_ff=512))
+        kv_heads=4, head_dim=32, d_ff=512, tie_embeddings=tie))
+    return cell, limit
+
+
+def test_control_fails_the_chat_limit():
+    """The control (the reference in float8) against the chat cell's
+    own limit."""
+    cell, limit = _control_cell(tie=True)
     res = _run(cell, control=True)
     g = res["gaps"]
     assert res["line"]["correct"], g
@@ -129,3 +140,16 @@ def test_control_fails_the_chat_limit():
     assert res["control"]["correct"] is False, res["control"]
     assert res["control"]["check"]["max_logit_gap"] == {
         "value": g["control_max_gap"], "limit": limit}
+
+
+def test_untied_cell_is_correct_and_its_control_is_not():
+    """A configuration with an untied head runs through the whole
+    harness with nothing but its model dict changed: the reference
+    module lays out ``lm_head`` and scores against it."""
+    cell, limit = _control_cell(tie=False)
+    res = _run(cell, control=True)
+    g = res["gaps"]
+    assert res["line"]["correct"], g
+    assert g["tokens"] > 20
+    assert res["control"]["correct"] is False, res["control"]
+    assert g["control_max_gap"] > limit, g

@@ -13,7 +13,6 @@ import harness
 import layers
 import scopes
 import tracereduce
-import weights
 from test_bench_trace import CHIP_TRACE, _chip_run
 
 RELAYOUT = "jit(_lambda)/while/body/closed_call/paged_attention/kv_relayout"
@@ -194,7 +193,7 @@ def test_a_traced_step_writes_its_phases_in_order(tmp_path):
     import jax
     from repro.runtime.serving import PagedServingEngine
     cfg = bench_tiny.config()
-    params = weights.make(cfg["model"], 3, cfg["weights_dtype"])
+    params = bench_tiny.params(cfg["model"], 3, cfg["weights_dtype"])
     eng = PagedServingEngine(harness.program_config(cfg), params,
                              page_size=16, num_pages=16, max_seats=2,
                              max_seq_len=64, prefill_chunk=32)
