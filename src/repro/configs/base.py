@@ -22,6 +22,18 @@ class MoEConfig:
     moe_offset: int = 0             # first MoE layer index within the period
     router_jitter: float = 0.0
     capacity_factor: float = 1.25   # capacity-impl slots = cf·T·k/E
+    norm_topk_prob: bool = True     # renormalise the top-k weights to sum 1
+    shared_gate: bool = True        # sigmoid gate on the shared expert
+    router_f32: bool = False        # router logits from float32 inputs
+    # The experts this device holds: the first ``experts_held`` (None:
+    # all).  The router keeps its width ``num_experts``; tokens routed
+    # elsewhere get nothing from here.
+    experts_held: Optional[int] = None
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +90,40 @@ class ModelConfig:
     # to zero output so the function is EXACTLY the unpadded model; the
     # win is 16-way sharding of attention instead of full replication.
     pad_heads_to: Optional[int] = None
+    # Multi-head latent attention (DeepSeek-V2): keys and values come
+    # from one cached latent of ``kv_lora_rank`` lanes and a rope key of
+    # ``qk_rope_head_dim`` lanes shared by all heads.  ``q_lora_rank``
+    # None: the query is one projection (the only form supported).
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading layers with a dense MLP (width d_ff) before the periodic stack
+    first_k_dense: int = 0
+    # RoPE scaling as published (``{"type": "yarn", "factor": ...}``),
+    # held as sorted (key, value) pairs so the config stays hashable
+    rope_scaling: Optional[Tuple[Tuple[str, object], ...]] = None
+
+    def __post_init__(self):
+        # a configuration file gives nested groups as dicts
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.q_lora_rank is not None:
+            raise NotImplementedError("q_lora_rank: only a one-projection "
+                                      "query is supported")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def stack_layers(self) -> int:
+        """Layers of the periodic stack (after the leading dense ones)."""
+        return self.num_layers - self.first_k_dense
 
     @property
     def padded_heads(self) -> int:
@@ -133,7 +179,8 @@ class ModelConfig:
         if self.moe is not None and self.moe.moe_every > 1:
             import math
             period = period * self.moe.moe_every // math.gcd(period, self.moe.moe_every)
-        assert self.num_layers % period == 0, (self.name, self.num_layers, period)
+        assert self.stack_layers % period == 0, (self.name, self.num_layers,
+                                                 period)
         return period
 
 
@@ -162,7 +209,7 @@ def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
     """A small same-family config for CPU smoke tests."""
     period = cfg.scan_period()
     small = dict(
-        num_layers=2 * period,
+        num_layers=cfg.first_k_dense + 2 * period,
         d_model=64,
         num_heads=4,
         num_kv_heads=min(cfg.num_kv_heads, 4) if cfg.num_kv_heads else 4,
@@ -180,7 +227,11 @@ def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         # assert exact prefill/decode consistency (production keeps 1.25)
         small["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
-            d_ff_shared=64 if cfg.moe.d_ff_shared else 0, capacity_factor=4.0)
+            d_ff_shared=64 if cfg.moe.d_ff_shared else 0, capacity_factor=4.0,
+            experts_held=cfg.moe.experts_held and 2)
+    if cfg.mla:
+        small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16)
     if cfg.ssm is not None:
         small["ssm"] = dataclasses.replace(
             cfg.ssm, d_state=16, head_dim=16, chunk_size=16)

@@ -26,6 +26,7 @@ _MODULES = {
     "whisper-base": "whisper_base",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_MODULES)
@@ -65,7 +66,7 @@ def cell_supported(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
     """Whether this (arch × shape) cell runs, per the assignment rules."""
     if shape.name == "long_500k" and not cfg.subquadratic:
         return False, ("skip: long_500k needs sub-quadratic attention; "
-                       f"{cfg.name} is pure full-attention (DESIGN.md §6)")
+                       f"{cfg.name} is pure full-attention")
     return True, ""
 
 

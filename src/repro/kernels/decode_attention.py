@@ -134,6 +134,11 @@ def decode_attention_pallas(q, k, v, lengths, *, bk: int = 256,
 # the same path with one more DMA per page and pool for the page's scale row;
 # the scales are applied to the f32 scores (K) and probabilities (V), which
 # equals dequantizing the tiles first.
+#
+# A latent pool (multi-head latent attention) is one pool: each row is a
+# token's [latent ; rope key], the key of every query head, and its first
+# ``v_lanes`` lanes are the value.  One DMA a page, one KV head, all query
+# heads in one program: the latent is read once per seat and block.
 
 _BLOCK_TOKENS = 256      # tokens one grid step covers at most
 _LANES = 128
@@ -189,18 +194,22 @@ def _scale_rows(x, page: int, g: int, rows: int):
     return out
 
 
-def _paged_block_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
-                        scale: float, page: int, ppcb: int, g: int, n: int,
-                        n_pages: int, quantized: bool):
+def _paged_block_kernel(len_ref, pt_ref, q_ref, *refs, scale: float,
+                        page: int, ppcb: int, g: int, n: int, n_pages: int,
+                        n_pools: int, v_lanes):
+    # refs: the pools in HBM, the output, the softmax scratch, one VMEM
+    # buffer per pool, the DMA semaphores and the slot; pools are (k, v),
+    # (k, v, k_scale, v_scale) or one latent pool whose first ``v_lanes``
+    # lanes are the value
+    hbm = refs[:n_pools]
+    o_ref, m_ref, l_ref, acc_ref = refs[n_pools:n_pools + 4]
+    bufs = refs[n_pools + 4:2 * n_pools + 4]
+    sems, slot_ref = refs[2 * n_pools + 4:]
+    pools = tuple(zip(hbm, bufs))
+    quantized = n_pools == 4
+    k_buf, v_buf = (bufs[0], bufs[0]) if n_pools == 1 else bufs[:2]
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, ks_buf,
-         vs_buf, sems, slot_ref) = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
-                 (vs_hbm, vs_buf))
-    else:
-        (o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sems,
-         slot_ref) = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+        ks_buf, vs_buf = bufs[2:]
     b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_kvh, n_blocks = pl.num_programs(1), pl.num_programs(2)
     bk = ppcb * page
@@ -251,7 +260,11 @@ def _paged_block_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
         rows, lanes = q.shape
         w = bk // g                                         # packed rows
         k = k_buf[slot].reshape(w, lanes)
-        v = v_buf[slot].reshape(w, lanes).astype(jnp.float32)
+        if v_lanes is None:
+            v = v_buf[slot].reshape(w, lanes).astype(jnp.float32)
+        else:
+            v = v_buf[slot, :, :, :v_lanes].reshape(w, v_lanes).astype(
+                jnp.float32)
         if quantized or q.dtype != k.dtype:
             q, k = q.astype(jnp.float32), k.astype(jnp.float32)
         # bf16 operands go to the MXU as they are: their products are
@@ -298,10 +311,14 @@ def _paged_block_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
         o_ref[...] = (num / jnp.maximum(den, 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_call(q, pools, scales, page_table, lengths, interpret):
+def _paged_call(q, pools, scales, page_table, lengths, interpret, *,
+                scale=None, v_lanes=None, name=None):
     """Shared launcher: ``pools`` is (k, v) of shape (KVH, P, page, d),
-    ``scales`` is () or (k_scale, v_scale) of shape (KVH, P, page)."""
+    ``scales`` is () or (k_scale, v_scale) of shape (KVH, P, page); or
+    ``pools`` is one latent pool and ``v_lanes`` the width of the value
+    it holds in its first lanes.  ``scale`` defaults to d^-0.5."""
     B, KVH, rep, d = q.shape
+    dv = d if v_lanes is None else v_lanes
     _, P, page, _ = pools[0].shape
     n = page_table.shape[1]
     ppcb = _pages_per_block(page, n)
@@ -331,25 +348,27 @@ def _paged_call(q, pools, scales, page_table, lengths, interpret):
         num_scalar_prefetch=2,                   # lengths, flat page table
         grid=(B, KVH, n // ppcb),
         in_specs=[seat_head(rows, lanes)] + [hbm] * len(flat),
-        out_specs=seat_head(rep, d),
+        out_specs=seat_head(rep, dv),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),  # running max, per row
             pltpu.VMEM((rows, 1), jnp.float32),  # running sum
-            pltpu.VMEM((rows, lanes), jnp.float32),
+            pltpu.VMEM((rows, v_lanes or lanes), jnp.float32),
             *[pltpu.VMEM((2, ppcb) + a.shape[1:], a.dtype) for a in flat],
             pltpu.SemaphoreType.DMA((len(flat), 2)),
             pltpu.SMEM((1,), jnp.int32),         # slot of the current block
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_block_kernel, scale=d ** -0.5, page=page,
-                          ppcb=ppcb, g=g, n=n, n_pages=P,
-                          quantized=bool(scales)),
+        functools.partial(_paged_block_kernel,
+                          scale=d ** -0.5 if scale is None else scale,
+                          page=page, ppcb=ppcb, g=g, n=n, n_pages=P,
+                          n_pools=len(flat), v_lanes=v_lanes),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, rep, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32).reshape(-1),
       qx, *flat)
 
@@ -390,3 +409,22 @@ def quantized_paged_decode_attention_pallas(q, k_pages, v_pages, k_scale,
         v_pages = jax.lax.bitcast_convert_type(v_pages, jnp.float8_e4m3fn)
     return _paged_call(q, (k_pages, v_pages), (k_scale, v_scale), page_table,
                        lengths, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("v_lanes", "scale", "interpret"))
+def paged_decode_attention_latent_pallas(q, pool, page_table, lengths, *,
+                                         v_lanes: int, scale: float,
+                                         interpret=False):
+    """Decode attention over a latent paged pool (multi-head latent
+    attention in its absorbed form).
+
+    q: (B, H, lanes) — each head's latent query; pool: (R, page, lanes),
+    one row per token: the key of every head, its first ``v_lanes`` lanes
+    the value; page_table: (B, n) int32 row indices into ``pool``;
+    lengths: (B,) valid-key counts.  Returns (B, H, v_lanes) in q.dtype.
+    The kernel is named ``paged_decode_attention_latent`` in a trace."""
+    B, H, lanes = q.shape
+    out = _paged_call(q[:, None], (pool[None],), (), page_table, lengths,
+                      interpret, scale=scale, v_lanes=v_lanes,
+                      name="paged_decode_attention_latent")
+    return out[:, 0]

@@ -160,3 +160,26 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         out = paged_decode_attention_pallas(qf, kf, vf, page_table, lengths,
                                             interpret=interpret)
     return out.reshape(B, 1, H, d)
+
+
+def paged_decode_attention_latent(q, pool, layer, page_table, lengths, *,
+                                  v_lanes: int, scale: float,
+                                  interpret: bool | None = None):
+    """Single-token decode over a latent paged pool of a layer stack.
+
+    q: (B, 1, H, lanes) latent queries; pool: (G, P, page, lanes), one
+    row a token for each of G layers; ``layer``: which of them (may be
+    traced); page_table: (B, n) int32; lengths: (B,) valid-key counts.
+    Returns (B, 1, H, v_lanes).  The pool is read where it lies: its
+    (G*P, page, lanes) view is free, and the layer's pages are the table
+    shifted by ``layer * P``."""
+    from repro.kernels.decode_attention import (
+        paged_decode_attention_latent_pallas)
+    interpret = (not _on_tpu()) if interpret is None else interpret
+    G, P, page, lanes = pool.shape
+    B, _, H, _ = q.shape
+    out = paged_decode_attention_latent_pallas(
+        q[:, 0], pool.reshape(G * P, page, lanes),
+        page_table + layer * P, lengths, v_lanes=v_lanes, scale=scale,
+        interpret=interpret)
+    return out[:, None]

@@ -158,17 +158,14 @@ def serve(arch: str, *, batch: int = 4, prompt_len: int = 32, gen: int = 16,
 
     t0 = time.perf_counter()
     logits, cache = prefill_fn(params, req)
-    # grow cache to max_len along the KV seq dim
-    def grow(pos_ent):
-        out = {}
-        for k, v in pos_ent.items():
-            if k in ("k", "v"):
-                pad = jnp.zeros(v.shape[:2] + (gen,) + v.shape[3:], v.dtype)
-                out[k] = jnp.concatenate([v, pad], axis=2)
-            else:
-                out[k] = v
-        return out
-    cache = {pos: grow(ent) for pos, ent in cache.items()}
+    # grow cache to max_len along the KV (or latent) seq dim; leading
+    # dense layers' caches sit one level down, under "lead"
+    def grow(path, v):
+        if path[-1].key not in ("k", "v", "latent"):
+            return v
+        pad = jnp.zeros(v.shape[:2] + (gen,) + v.shape[3:], v.dtype)
+        return jnp.concatenate([v, pad], axis=2)
+    cache = jax.tree_util.tree_map_with_path(grow, cache)
     t_prefill = time.perf_counter() - t0
 
     tok = pick(logits[:, -1], 0)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import mixed_precision
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.modules import (ParamSpec, is_spec, rms_norm, swiglu,
@@ -71,6 +72,8 @@ def _block_specs(cfg: ModelConfig, *, decoder_side: bool = True) -> Dict[str, An
         sub: Dict[str, Any] = {"ln1": ParamSpec((cfg.d_model,), ("embed",), "zeros")}
         if kind == "ssm":
             sub["mixer"] = ssm_mod.ssm_specs(cfg)
+        elif cfg.mla:
+            sub["mixer"] = mla_mod.mla_specs(cfg)
         else:
             sub["mixer"] = attn_mod.attn_specs(cfg)
         if cfg.encoder_decoder and decoder_side:
@@ -92,14 +95,24 @@ def _stack(specs, g: int):
         specs, is_leaf=is_spec)
 
 
+def lead_config(cfg: ModelConfig) -> ModelConfig:
+    """The leading dense layers (``first_k_dense``) as a stack of their
+    own: the same mixer, a dense MLP of width ``d_ff``."""
+    return dataclasses.replace(cfg, moe=None, num_layers=cfg.first_k_dense,
+                               first_k_dense=0)
+
+
 def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
     period = cfg.scan_period()
-    g = cfg.num_layers // period
+    g = cfg.stack_layers // period
     specs: Dict[str, Any] = {
         "embed": ParamSpec((cfg.vocab_size, cfg.d_model), ("vocab", "embed")),
         "blocks": _stack(_block_specs(cfg), g),
         "final_norm": ParamSpec((cfg.d_model,), ("embed",), "zeros"),
     }
+    if cfg.first_k_dense:
+        specs["lead"] = _stack(_block_specs(lead_config(cfg)),
+                               cfg.first_k_dense)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((cfg.d_model, cfg.vocab_size), ("embed", "vocab"))
     if cfg.encoder_decoder:
@@ -153,6 +166,11 @@ def _apply_sublayer(p, cfg, x, kind, mlpk, positions, rules, opts,
         if want_cache:
             cache["conv"] = conv_tail
             cache["ssm"] = ssm_state
+    elif cfg.mla:
+        mix, rows = mla_mod.attention(p["mixer"], cfg, h, positions,
+                                      opts.q_chunk)
+        if want_cache:
+            cache["latent"] = rows
     else:
         mix, (k, v) = attn_mod.attention(
             p["mixer"], cfg, h, kind=kind, positions=positions,
@@ -214,6 +232,25 @@ def backbone(params_blocks, cfg: ModelConfig, x, positions, rules, opts,
     return x, aux, caches
 
 
+def _forward(params, cfg: ModelConfig, x, positions, rules, opts, *,
+             enc_out=None, want_cache=False, train: bool = False):
+    """The leading dense layers (if any), then the periodic stack.
+    Returns (x, aux_loss_sum, caches or None); the leading layers'
+    caches sit under ``"lead"``."""
+    if not cfg.first_k_dense:
+        return backbone(params["blocks"], cfg, x, positions, rules, opts,
+                        enc_out=enc_out, want_cache=want_cache, train=train)
+    x, aux0, lead = backbone(params["lead"], lead_config(cfg), x, positions,
+                             rules, opts, enc_out=enc_out,
+                             want_cache=want_cache, train=train)
+    x, aux, caches = backbone(params["blocks"], cfg, x, positions, rules,
+                              opts, enc_out=enc_out, want_cache=want_cache,
+                              train=train)
+    if want_cache:
+        caches = dict(caches, lead=lead)
+    return x, aux0 + aux, caches
+
+
 def _embed_inputs(params, cfg: ModelConfig, batch, rules):
     """Token (+ modality stub) embedding. Returns (x, positions, enc_out)."""
     tokens = batch["tokens"]
@@ -269,7 +306,7 @@ def lm_loss(params, cfg: ModelConfig, batch, rules: LogicalRules,
     """Mean next-token cross-entropy (+ MoE aux loss)."""
     params = _maybe_bf16(params, opts)
     x, positions, enc_out = _embed_inputs(params, cfg, batch, rules)
-    x, aux, _ = backbone(params["blocks"], cfg, x, positions, rules, opts,
+    x, aux, _ = _forward(params, cfg, x, positions, rules, opts,
                          enc_out=enc_out, train=True)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     total, count = softmax_xent_chunked(
@@ -286,7 +323,7 @@ def prefill(params, cfg: ModelConfig, batch, rules: LogicalRules,
             opts: RunOptions = RunOptions()):
     """Run the prompt through the model; return (last_logits, cache)."""
     x, positions, enc_out = _embed_inputs(params, cfg, batch, rules)
-    x, _, caches = backbone(params["blocks"], cfg, x, positions, rules, opts,
+    x, _, caches = _forward(params, cfg, x, positions, rules, opts,
                             enc_out=enc_out, want_cache=True)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     last = x[:, -1:]
@@ -312,6 +349,9 @@ def cache_entry_shapes(cfg: ModelConfig, pos_idx: int, batch: int, seq: int,
         ent["conv"] = ((batch, w - 1, conv_dim), ("batch", None, "ssm_inner"))
         ent["ssm"] = ((batch, nheads, cfg.ssm.d_state, cfg.ssm.head_dim),
                       ("batch", "ssm_heads", None, None))
+    elif cfg.mla:
+        ent["latent"] = ((batch, seq, mla_mod.latent_lanes(cfg)),
+                         ("batch", "seq_shard", None))
     else:
         t = seq
         if kind == "attn_local" and opts.ring_local_cache and cfg.sliding_window:
@@ -330,7 +370,7 @@ def cache_specs(cfg: ModelConfig, batch: int, seq: int,
                 opts: RunOptions = RunOptions()):
     """(abstract_cache, axes_tree) for decode-cell dry-runs."""
     period = cfg.scan_period()
-    g = cfg.num_layers // period
+    g = cfg.stack_layers // period
     dt = jnp.dtype(cfg.compute_dtype)
     shapes, axes = {}, {}
     for i in range(period):
@@ -340,6 +380,9 @@ def cache_specs(cfg: ModelConfig, batch: int, seq: int,
                                     jnp.float32 if k in ("ssm",) else dt)
             for k, (s, _) in ent.items()}
         axes[f"pos{i}"] = {k: ("layers",) + a for k, (_, a) in ent.items()}
+    if cfg.first_k_dense:
+        shapes["lead"], axes["lead"] = cache_specs(lead_config(cfg), batch,
+                                                   seq, opts)
     return shapes, axes
 
 
@@ -352,7 +395,8 @@ def init_cache(cfg: ModelConfig, batch: int, seq: int,
 
 def paged_cache_supported(cfg: ModelConfig) -> bool:
     """The paged layout covers pure-attention decoders (global and
-    sliding-window layers).  SSM state and encoder K/V are fixed-size per
+    sliding-window layers, and latent attention, whose pool holds one
+    latent row a token).  SSM state and encoder K/V are fixed-size per
     request — nothing to page — so those archs stay on the slot engine."""
     return (not cfg.encoder_decoder and cfg.frontend is None
             and all(k in ("attn", "attn_local") for k in cfg.layer_kinds()))
@@ -371,10 +415,28 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     (fp8/int8) add f32 ``ks``/``vs`` scale leaves of
     (G, num_pages, page, KVH) — one scale per stored (token, head)
     vector, page-adjacent so copy-on-write and donation treat values
-    and scales as one pytree."""
+    and scales as one pytree.
+
+    Latent attention (``cfg.mla``) keeps one row of
+    ``mla.latent_lanes(cfg)`` a token per layer, in the layout its
+    decode kernel reads: {posN: {latent: (G, num_pages, page, lanes)}},
+    and the leading dense layers' under ``"lead"``.  It takes no
+    quantized dtype."""
     assert paged_cache_supported(cfg), cfg.name
     period = cfg.scan_period()
-    g = cfg.num_layers // period
+    g = cfg.stack_layers // period
+    if cfg.mla:
+        if kv_dtype is not None and mixed_precision.kv_is_quantized(kv_dtype):
+            raise NotImplementedError("a latent pool is not quantized")
+        dt = jnp.dtype(cfg.compute_dtype if kv_dtype is None else
+                       mixed_precision.kv_storage_dtype(kv_dtype))
+        shape = (g, num_pages, page_size, mla_mod.latent_lanes(cfg))
+        out = {f"pos{i}": {"latent": jnp.zeros(shape, dt)}
+               for i in range(period)}
+        if cfg.first_k_dense:
+            out["lead"] = init_paged_cache(lead_config(cfg), num_pages,
+                                           page_size, kv_dtype)
+        return out
     kvh, hd = cfg.padded_kv_heads, cfg.resolved_head_dim
     if kv_dtype is None:
         dt, quantized = jnp.dtype(cfg.compute_dtype), False
@@ -400,6 +462,11 @@ def paged_page_bytes(cfg: ModelConfig, page_size: int,
     layers, K and V, values plus scales for quantized dtypes) — the
     figure byte-denominated budget accounting compares across engines
     of different precisions (runtime.router.HostBudget)."""
+    if cfg.mla:
+        dt = jnp.dtype(cfg.compute_dtype if kv_dtype is None else
+                       mixed_precision.kv_storage_dtype(kv_dtype))
+        return (cfg.num_layers * page_size * mla_mod.latent_lanes(cfg)
+                * dt.itemsize)
     kvh, hd = cfg.padded_kv_heads, cfg.resolved_head_dim
     if kv_dtype is None:
         tok = kvh * hd * jnp.dtype(cfg.compute_dtype).itemsize
@@ -437,40 +504,75 @@ def paged_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
 
     Returns (logits (A, C, V) fp32, new_cache).
     """
-    kinds = cfg.layer_kinds()
-    mlps = cfg.mlp_kinds()
     dt = jnp.dtype(cfg.compute_dtype)
     A, C = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     x = _constraint(x, rules, ("batch", None, None))
     qpos = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
 
-    def block(x, blk_and_cache):
-        blk, cac = blk_and_cache
-        new_cac = {}
-        for i, (kind, mlpk) in enumerate(zip(kinds, mlps)):
-            p = blk[f"pos{i}"]
-            c = cac[f"pos{i}"]
-            h = rms_norm(x, p["ln1"], cfg.norm_eps)
-            mix, nc = attn_mod.paged_attention(
-                p["mixer"], cfg, h, c, page_table, qpos,
-                n_valid, kind=kind, impl=opts.paged_attn_impl)
-            x = x + mix
-            with jax.named_scope("mlp"):
-                if mlpk == "moe":
-                    hh = rms_norm(x, p["ln2"], cfg.norm_eps)
-                    x = x + moe_mod.moe_block(
-                        p["moe"], cfg, hh, rules=rules, mesh=opts.mesh,
-                        xaxes=("batch", None, None), impl=opts.moe_impl)
-                elif mlpk == "dense":
-                    hh = rms_norm(x, p["ln2"], cfg.norm_eps)
-                    x = x + swiglu(hh, p["mlp"]["wg"], p["mlp"]["wu"],
-                                   p["mlp"]["wd"], x.dtype)
-            new_cac[f"pos{i}"] = nc
-        return x, new_cac
+    def mlp(p, scfg, x, mlpk):
+        with jax.named_scope("mlp"):
+            if mlpk == "moe":
+                hh = rms_norm(x, p["ln2"], scfg.norm_eps)
+                x = x + moe_mod.moe_block(
+                    p["moe"], scfg, hh, rules=rules, mesh=opts.mesh,
+                    xaxes=("batch", None, None), impl=opts.moe_impl)
+            elif mlpk == "dense":
+                hh = rms_norm(x, p["ln2"], scfg.norm_eps)
+                x = x + swiglu(hh, p["mlp"]["wg"], p["mlp"]["wu"],
+                               p["mlp"]["wd"], x.dtype)
+        return x
 
-    x, new_cache = jax.lax.scan(
-        lambda carry, xs: block(carry, xs), x, (params["blocks"], cache))
+    def stack(x, blocks, cache, scfg):
+        kinds = scfg.layer_kinds()
+        mlps = scfg.mlp_kinds()
+
+        def block(x, blk_and_cache):
+            blk, cac = blk_and_cache
+            new_cac = {}
+            for i, (kind, mlpk) in enumerate(zip(kinds, mlps)):
+                p = blk[f"pos{i}"]
+                c = cac[f"pos{i}"]
+                h = rms_norm(x, p["ln1"], scfg.norm_eps)
+                mix, nc = attn_mod.paged_attention(
+                    p["mixer"], scfg, h, c, page_table, qpos,
+                    n_valid, kind=kind, impl=opts.paged_attn_impl)
+                x = x + mix
+                x = mlp(p, scfg, x, mlpk)
+                new_cac[f"pos{i}"] = nc
+            return x, new_cac
+
+        def latent_block(carry, blk):
+            # the latent pool rides in the carry and is written in place;
+            # the kernel reads layer ``g`` of it where it lies
+            x, cac, g = carry
+            new_cac = {}
+            for i, mlpk in enumerate(mlps):
+                p = blk[f"pos{i}"]
+                h = rms_norm(x, p["ln1"], scfg.norm_eps)
+                mix, pool = mla_mod.paged_attention(
+                    p["mixer"], scfg, h, cac[f"pos{i}"]["latent"], g,
+                    page_table, qpos, n_valid, impl=opts.paged_attn_impl,
+                    q_chunk=opts.q_chunk)
+                x = mlp(p, scfg, x + mix, mlpk)
+                new_cac[f"pos{i}"] = {"latent": pool}
+            return (x, new_cac, g + 1), None
+
+        if scfg.mla:
+            (x, cache, _), _ = jax.lax.scan(
+                latent_block, (x, cache, jnp.int32(0)), blocks)
+            return x, cache
+        return jax.lax.scan(lambda carry, xs: block(carry, xs), x,
+                            (blocks, cache))
+
+    if cfg.first_k_dense:
+        x, lead = stack(x, params["lead"], cache["lead"], lead_config(cfg))
+        x, new_cache = stack(
+            x, params["blocks"],
+            {k: v for k, v in cache.items() if k != "lead"}, cfg)
+        new_cache = dict(new_cache, lead=lead)
+    else:
+        x, new_cache = stack(x, params["blocks"], cache, cfg)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x,
@@ -525,11 +627,30 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
 
     Returns (logits (B,1,V) fp32, new_cache).
     """
-    kinds = cfg.layer_kinds()
-    mlps = cfg.mlp_kinds()
     dt = jnp.dtype(cfg.compute_dtype)
     x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     x = _constraint(x, rules, ("batch", None, None))
+    if cfg.first_k_dense:
+        x, lead = _decode_stack(params["lead"], lead_config(cfg),
+                                cache["lead"], x, pos, rules, opts)
+        x, new_cache = _decode_stack(
+            params["blocks"], cfg,
+            {k: v for k, v in cache.items() if k != "lead"}, x, pos, rules,
+            opts)
+        new_cache = dict(new_cache, lead=lead)
+    else:
+        x, new_cache = _decode_stack(params["blocks"], cfg, cache, x, pos,
+                                     rules, opts)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = jnp.einsum("bsd,dv->bsv", x,
+                        _output_weight(params, cfg).astype(x.dtype))
+    return logits.astype(jnp.float32), new_cache
+
+
+def _decode_stack(blocks, cfg: ModelConfig, cache, x, pos, rules, opts):
+    """``decode_step``'s scan over one layer stack."""
+    kinds = cfg.layer_kinds()
+    mlps = cfg.mlp_kinds()
 
     def block(x, blk_and_cache):
         blk, cac = blk_and_cache
@@ -539,7 +660,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
             c = cac[f"pos{i}"]
             nc = {}
             h = rms_norm(x, p["ln1"], cfg.norm_eps)
-            if kind == "ssm":
+            if cfg.mla:
+                mix, nc["latent"] = mla_mod.decode_attention(
+                    p["mixer"], cfg, h, c["latent"], pos)
+            elif kind == "ssm":
                 mix, (conv, ssm) = ssm_mod.ssm_block(
                     p["mixer"], cfg, h, conv_state=c["conv"],
                     ssm_state=c["ssm"], decode=True)
@@ -574,9 +698,5 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
             new_cac[f"pos{i}"] = nc
         return x, new_cac
 
-    x, new_cache = jax.lax.scan(
-        lambda carry, xs: block(carry, xs), x, (params["blocks"], cache))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x,
-                        _output_weight(params, cfg).astype(x.dtype))
-    return logits.astype(jnp.float32), new_cache
+    return jax.lax.scan(lambda carry, xs: block(carry, xs), x,
+                        (blocks, cache))

@@ -74,16 +74,64 @@ def rms_norm(x, w, eps=1e-6):
     return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def rope(x, positions, theta=10_000.0):
-    """Rotary embedding. x: (..., seq, heads..., head_dim); positions (..., seq)."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor (DeepSeek's ``yarn_get_mscale``)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rope_frequencies(dim: int, theta: float, scaling=None):
+    """(inverse frequencies (dim/2,) float32, factor on cos and sin) of a
+    rope over ``dim`` lanes.  ``scaling`` is None (plain RoPE) or a yarn
+    group as a config publishes it (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``): the low frequencies are divided by
+    ``factor``, the high ones kept, with a linear ramp between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context (transformers' ``_compute_yarn_parameters``)."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if scaling is None:
+        return (1.0 / pos).astype(np.float32), 1.0
+    s = dict(scaling)
+    if s.get("type", s.get("rope_type")) != "yarn":
+        raise NotImplementedError(f"rope scaling {s!r}")
+    factor = float(s["factor"])
+    orig = float(s["original_max_position_embeddings"])
+    fast, slow = float(s.get("beta_fast", 32)), float(s.get("beta_slow", 1))
+
+    def corr(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(fast)), 0)
+    high = min(math.ceil(corr(slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                      # share of the unscaled frequency
+    inv = (1.0 / (factor * pos)) * (1.0 - keep) + (1.0 / pos) * keep
+    m, m_all = s.get("mscale"), s.get("mscale_all_dim")
+    attn = (yarn_mscale(factor, m) / yarn_mscale(factor, m_all)
+            if m and m_all else yarn_mscale(factor))
+    return inv.astype(np.float32), float(attn)
+
+
+def rope(x, positions, theta=10_000.0, scaling=None):
+    """Rotary embedding. x: (..., seq, heads..., head_dim); positions (..., seq).
+    Rotate-half layout: lane i pairs with lane i + head_dim/2.
+    ``scaling``: see ``rope_frequencies``."""
     hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+        attn = 1.0
+    else:
+        freqs, attn = rope_frequencies(hd, theta, scaling)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., seq, hd/2)
     # insert singleton axes for head dims between seq and head_dim
     extra = x.ndim - angles.ndim
     for _ in range(extra):
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if attn != 1.0:
+        cos, sin = cos * attn, sin * attn
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -2,7 +2,7 @@
 
 Tokens are routed top-k, replicated k times, sorted by expert id, and pushed
 through grouped GEMMs (``ragged_dot``) — the TPU-native analogue of
-megablocks.  Sharding strategy (DESIGN.md §5): the expert FFN hidden dim is
+megablocks.  Sharding strategy: the expert FFN hidden dim is
 tensor-parallel over the ``model`` axis ("MoE-TP"), which divides evenly for
 any expert count (60, 16, 8) on the fixed 16-wide model axis; routing + sort
 stay *local* to each data shard, expressed with ``jax.shard_map`` so no
@@ -27,11 +27,12 @@ from repro.parallel.sharding import spec_for
 def moe_specs(cfg) -> dict:
     m = cfg.moe
     d = cfg.d_model
+    e = m.held                      # expert weights held here
     specs = {
         "router": ParamSpec((d, m.num_experts), ("embed", "expert")),
-        "wg": ParamSpec((m.num_experts, d, m.d_ff_expert), ("expert", "embed", "expert_ff")),
-        "wu": ParamSpec((m.num_experts, d, m.d_ff_expert), ("expert", "embed", "expert_ff")),
-        "wd": ParamSpec((m.num_experts, m.d_ff_expert, d), ("expert", "expert_ff", "embed")),
+        "wg": ParamSpec((e, d, m.d_ff_expert), ("expert", "embed", "expert_ff")),
+        "wu": ParamSpec((e, d, m.d_ff_expert), ("expert", "embed", "expert_ff")),
+        "wd": ParamSpec((e, m.d_ff_expert, d), ("expert", "expert_ff", "embed")),
     }
     if m.num_shared_experts:
         f_sh = m.d_ff_shared or m.num_shared_experts * m.d_ff_expert
@@ -39,8 +40,9 @@ def moe_specs(cfg) -> dict:
             "wg": ParamSpec((d, f_sh), ("embed", "ff")),
             "wu": ParamSpec((d, f_sh), ("embed", "ff")),
             "wd": ParamSpec((f_sh, d), ("ff", "embed")),
-            "gate": ParamSpec((d, 1), ("embed", None)),
         }
+        if m.shared_gate:
+            specs["shared"]["gate"] = ParamSpec((d, 1), ("embed", None))
     return specs
 
 
@@ -93,6 +95,15 @@ def _moe_local(p, cfg, x, *, psum_axis=None, impl: str = "capacity"):
 
     impl: 'capacity' (portable, honest FLOPs, capacity drops) or
           'ragged' (dropless ragged_dot — the TPU production path).
+
+    The router scores all ``num_experts`` (from float32 inputs where
+    ``router_f32``, as DeepSeek's published gate does) and picks the top ``top_k``; the weights are renormalised
+    to sum 1 where ``norm_topk_prob``.  Of the picks, only the experts
+    held here (the first ``MoEConfig.experts_held``) are
+    computed; a token routed elsewhere gets nothing from them here.  An
+    expert share is always dropless (ragged): a capacity drop would make
+    a token's result depend on the rest of its batch.  The shared expert
+    is added once, behind a sigmoid gate where ``shared_gate``.
     """
     m = cfg.moe
     B, S, D = x.shape
@@ -100,31 +111,47 @@ def _moe_local(p, cfg, x, *, psum_axis=None, impl: str = "capacity"):
     xt = x.reshape(B * S, D)
     T = B * S
 
-    logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = jax.lax.top_k(probs, m.top_k)               # (T, k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    with jax.named_scope("moe_route"):
+        if m.router_f32:
+            logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                                p["router"].astype(jnp.float32))
+        else:
+            logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt)
+                                ).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, m.top_k)           # (T, k)
+        if m.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        here = idx < m.held
+        # absent experts sort last, past every held group
+        flat_expert = jnp.where(here, idx, m.held).reshape(-1)  # (T*k,)
+        sort_idx = jnp.argsort(flat_expert)                     # stable
+        tok_ids = sort_idx // m.top_k                           # source token per slot
+        xs = jnp.take(xt, tok_ids, axis=0)                      # (T*k, D)
+        group_sizes = jnp.bincount(flat_expert, length=m.held + 1)[
+            :m.held].astype(jnp.int32)
+        w_sorted = jnp.take(jnp.where(here, weights, 0.0).reshape(-1),
+                            sort_idx, axis=0).astype(dt)
 
-    flat_expert = idx.reshape(-1)                               # (T*k,)
-    sort_idx = jnp.argsort(flat_expert)                         # stable
-    tok_ids = sort_idx // m.top_k                               # source token per slot
-    xs = jnp.take(xt, tok_ids, axis=0)                          # (T*k, D)
-    group_sizes = jnp.bincount(flat_expert, length=m.num_experts).astype(jnp.int32)
-
-    if impl == "ragged":
-        y = _expert_gemms_ragged(p, m, xs, group_sizes, dt)
-    else:
-        y = _expert_gemms_capacity(p, m, xs, group_sizes, dt)
-
-    w_sorted = jnp.take(weights.reshape(-1), sort_idx, axis=0).astype(dt)
-    out = jnp.zeros((T, D), dt).at[tok_ids].add(y * w_sorted[:, None])
+    with jax.named_scope("moe_experts"):
+        if impl == "ragged" or m.experts_held is not None:
+            y = _expert_gemms_ragged(p, m, xs, group_sizes, dt)
+            # slots routed to absent experts lie past the groups
+            held_slot = jnp.arange(xs.shape[0]) < jnp.sum(group_sizes)
+            y = jnp.where(held_slot[:, None], y, 0.0)
+        else:
+            y = _expert_gemms_capacity(p, m, xs, group_sizes, dt)
+        out = jnp.zeros((T, D), dt).at[tok_ids].add(y * w_sorted[:, None])
 
     if "shared" in p:
-        sh = p["shared"]
-        ys = swiglu(xt, sh["wg"], sh["wu"], sh["wd"], dt)
-        gate = jax.nn.sigmoid(
-            jnp.einsum("td,dz->tz", xt, sh["gate"].astype(dt)).astype(jnp.float32))
-        out = out + ys * gate.astype(dt)
+        with jax.named_scope("moe_shared"):
+            sh = p["shared"]
+            ys = swiglu(xt, sh["wg"], sh["wu"], sh["wd"], dt)
+            if "gate" in sh:
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "td,dz->tz", xt, sh["gate"].astype(dt)).astype(jnp.float32))
+                ys = ys * gate.astype(dt)
+            out = out + ys
 
     if psum_axis is not None:
         out = jax.lax.psum(out, psum_axis)
@@ -154,8 +181,9 @@ def moe_block(p, cfg, x, rules=None, mesh=None,
             "wg": spec_for(("embed", "ff"), rules),
             "wu": spec_for(("embed", "ff"), rules),
             "wd": spec_for(("ff", "embed"), rules),
-            "gate": spec_for(("embed", None), rules),
         }
+        if "gate" in p["shared"]:
+            pspec["shared"]["gate"] = spec_for(("embed", None), rules)
 
     # FSDP: if the "embed" (d_model) weight dim is sharded, gather it inside
     # the shard_map body before use (manual regions don't get GSPMD's

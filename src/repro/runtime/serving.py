@@ -846,6 +846,10 @@ class FixedSlotPolicy:
 
     def __init__(self, cfg, params, *, slots: int, max_len: int,
                  rules: LogicalRules, opts: Optional[M.RunOptions]):
+        if cfg.mla or cfg.first_k_dense:
+            # the slot placement below knows one level of K/V caches
+            raise ValueError(f"{cfg.name}: latent caches and leading dense "
+                             "layers are served by PagedServingEngine")
         self.cfg = cfg
         self.params = params
         self.slots = slots

@@ -74,12 +74,15 @@ def test_prefill_decode_consistency(arch, arch_setup):
     _, cache1 = jax.jit(
         lambda p, b: M.prefill(p, cfg, b, SINGLE_DEVICE_RULES, OPTS))(params, batch1)
 
-    def pad(ent):
-        return {k: (jnp.concatenate(
+    def pad(path, v):
+        # one more position on the sequence axis of every K/V (or latent)
+        # cache; the leading dense layers' caches sit under "lead"
+        if path[-1].key not in ("k", "v", "latent"):
+            return v
+        return jnp.concatenate(
             [v, jnp.zeros(v.shape[:2] + (1,) + v.shape[3:], v.dtype)], axis=2)
-            if k in ("k", "v") else v) for k, v in ent.items()}
 
-    cache1 = {pos: pad(ent) for pos, ent in cache1.items()}
+    cache1 = jax.tree_util.tree_map_with_path(pad, cache1)
     tok = batch["tokens"][:, S - 1:S]
     pos = jnp.full((B,), S - 1, jnp.int32)
     logits_d, _ = jax.jit(
@@ -105,6 +108,7 @@ def test_exact_assigned_config(arch):
         "whisper-base": (6, 512, 8, 8, 2048, 51865),
         "jamba-v0.1-52b": (32, 4096, 32, 8, 14336, 65536),
         "phi-3-vision-4.2b": (32, 3072, 32, 32, 8192, 32064),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff if cfg.moe is None or arch != "qwen2-moe-a2.7b"

@@ -108,3 +108,20 @@ def test_fused_decode_tick_compiles_and_fits_v5e(one_chip, monkeypatch):
                         vec(f32), vec(i32), vec(f32), vec(u32), vec(u32),
                         vec(u32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_paged_decode_kernel_compiles_for_v5e(one_chip):
+    """The latent (MLA) kernel at deepseek-v2-lite's widths: 16 heads of
+    a 640-lane row (512 latent + 64 rope + 64 zero lanes), a 512-lane
+    value, 16 seats, the 26 expert layers' pool of 8192 pages each,
+    tables of 768 pages (12288 tokens)."""
+    from repro.kernels.decode_attention import (
+        paged_decode_attention_latent_pallas)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = S((16, 16, 640), jnp.bfloat16)
+    pool = S((26 * 8192, PAGE, 640), jnp.bfloat16)
+    pt, lens = S((16, 768), jnp.int32), S((16,), jnp.int32)
+    compiled = paged_decode_attention_latent_pallas.lower(
+        q, pool, pt, lens, v_lanes=512, scale=0.11, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
